@@ -79,10 +79,11 @@ def measure_wallclock():
     import numpy as np
 
     from repro.core import ParallelContext, sp_attention
+    from repro.core.compat import make_mesh
     from repro.core.strategies import get_strategy, ineligible_reason, registered_strategies
     from repro.core.zigzag import to_zigzag
 
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = make_mesh((1, 4), ("data", "model"))
     S, Hq, Dh = 24000 // 5, 32, 64  # scaled for CPU (shape-preserving)
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((1, S, Hq, Dh)), jnp.float32)

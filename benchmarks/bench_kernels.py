@@ -135,7 +135,7 @@ def _bench_decode(rng):
     use n=1).  The *peak-buffer* column is the structural point and is exact
     from the declared shapes: the gather path materializes the slot's full
     ``(B, W*page_size, Hkv, D)`` K and V views; the fused kernel's largest
-    live buffer is one double-buffered page block + the ``(group, D)``
+    live buffer is one double-buffered page block + the ``(Hkv, group, D)``
     accumulators (``kernel_buffer_shapes("paged_decode")``), independent of
     context length.
     """
@@ -171,7 +171,7 @@ def _bench_decode(rng):
             # double-buffered per-grid-step blocks + scratch, page-count free
             "pallas_interpret": vmem_estimate(
                 "paged_decode", block_q=Hq // Hkv, block_k=ps, D=D,
-                data_bytes=itemsize,
+                data_bytes=itemsize, n_kv_heads=Hkv,
             ),
         }
         for impl, n in (("xla", 5), ("pallas_interpret", 1)):

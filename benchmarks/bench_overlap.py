@@ -50,11 +50,12 @@ def bench(S_list, repeats=3, out_path=OUT_PATH):
 
     from repro.core import ParallelContext, sp_attention
     from repro.core.api import AttnShapes
+    from repro.core.compat import make_mesh
     from repro.core.zigzag import to_zigzag
     from repro.launch.hlo_analysis import overlap_report
 
     P_sp = 4
-    mesh = jax.make_mesh((1, P_sp), ("data", "model"))
+    mesh = make_mesh((1, P_sp), ("data", "model"))
     rng = np.random.default_rng(0)
     results = {}
     for strategy in STRATEGIES:

@@ -1,11 +1,12 @@
 """Benchmark harness: one section per paper table/figure + roofline summary.
 
 Prints ``name,us_per_call,derived`` CSV at the end (harness convention).
+Any section that fails stops the run with a nonzero exit.
 
   * Table 1 analog  — per-scheme communication volumes (bench_comm_volume)
   * Figure 6 analog — per-step times, ring vs tokenring (bench_attention_steps;
-    modeled on v5e constants + measured on 4 simulated devices in a
-    subprocess so this process keeps a single CPU device)
+    modeled on v5e constants + measured on 4 simulated devices in a child
+    process, started before this process imports JAX)
   * serving — chunked-prefill TTFT / decode tok/s + per-schedule planner
     link bytes (bench_serving)
   * kernel micro-benchmarks (bench_kernels) — also writes the
@@ -22,51 +23,52 @@ import subprocess
 import sys
 
 
+def _child(module: str, title: str, timeout: int) -> None:
+    """Run a 4-simulated-device section in a child process; exit if it fails."""
+    print("=" * 72)
+    print(title, flush=True)
+    proc = subprocess.run(
+        [sys.executable, "-m", module], capture_output=True, text=True,
+        env=dict(os.environ, PYTHONPATH="src"), timeout=timeout,
+    )
+    print(proc.stdout[-2000:])
+    if proc.returncode != 0:
+        sys.exit(f"{module} failed (exit {proc.returncode}):\n{proc.stderr[-2000:]}")
+
+
 def main() -> None:
+    # The multi-device sections run first, in children: this process imports
+    # no JAX until they have exited, so it never holds a device they need.
+    _child(
+        "benchmarks.bench_attention_steps",
+        "Figure 6 analog: measured wall-clock (4 simulated devices)", 900,
+    )
+    # writes benchmarks/BENCH_overlap.json (sequential vs pipelined wall time
+    # + modeled overlap + HLO dependency evidence)
+    _child(
+        "benchmarks.bench_overlap",
+        "Overlap: sequential vs pipelined executor (4 simulated devices)", 3000,
+    )
+
+    from benchmarks import (
+        bench_attention_steps,
+        bench_comm_volume,
+        bench_kernels,
+        bench_serving,
+        roofline_report,
+    )
+
     rows = []
-
-    from benchmarks import bench_comm_volume, bench_kernels
-
     print("=" * 72)
     print("Table 1 analog: communication volumes")
     rows += bench_comm_volume.run()
 
     print("=" * 72)
     print("Figure 6 analog: per-step attention times (modeled)")
-    from benchmarks import bench_attention_steps
-
     rows += bench_attention_steps.run()
-
-    # measured wall-clock needs 4 devices -> subprocess
-    print("=" * 72)
-    print("Figure 6 analog: measured wall-clock (4 simulated devices)")
-    env = dict(os.environ)
-    env["PYTHONPATH"] = "src"
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_attention_steps"],
-        capture_output=True, text=True, env=env, timeout=900,
-    )
-    print(proc.stdout[-2000:])
-    if proc.returncode != 0:
-        print("measured-bench subprocess failed:", proc.stderr[-1000:])
-
-    # overlap executor bench needs 4 devices -> subprocess; writes
-    # benchmarks/BENCH_overlap.json (sequential vs pipelined wall time +
-    # modeled overlap + HLO dependency evidence)
-    print("=" * 72)
-    print("Overlap: sequential vs pipelined executor (4 simulated devices)")
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_overlap"],
-        capture_output=True, text=True, env=env, timeout=3000,
-    )
-    print(proc.stdout[-2000:])
-    if proc.returncode != 0:
-        print("overlap-bench subprocess failed:", proc.stderr[-1000:])
 
     print("=" * 72)
     print("Serving: chunked prefill TTFT + planner link bytes per schedule")
-    from benchmarks import bench_serving
-
     rows += bench_serving.run()
 
     print("=" * 72)
@@ -74,13 +76,8 @@ def main() -> None:
     rows += bench_kernels.run(json_path=bench_kernels.DEFAULT_JSON)
 
     print("=" * 72)
-    print("Roofline summary (from dry-run artifacts)")
-    try:
-        from benchmarks import roofline_report
-
-        roofline_report.main()
-    except Exception as e:  # artifacts may not exist yet
-        print("roofline report unavailable:", e)
+    print("Roofline summary (from dry-run artifacts, where present)")
+    roofline_report.main()
 
     print("=" * 72)
     print("name,us_per_call,derived")

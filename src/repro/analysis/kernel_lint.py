@@ -50,12 +50,15 @@ def _elem_bytes(elem: str, data_bytes: int) -> int:
 
 
 def vmem_estimate(
-    kind: str, *, block_q: int, block_k: int, D: int, data_bytes: int
+    kind: str, *, block_q: int, block_k: int, D: int, data_bytes: int,
+    n_kv_heads: int = 1,
 ) -> int:
     """Estimated VMEM bytes of one kernel's per-grid-step working set."""
     from repro.kernels.flash_attention import kernel_buffer_shapes
 
-    shapes = kernel_buffer_shapes(kind, block_q=block_q, block_k=block_k, D=D)
+    shapes = kernel_buffer_shapes(
+        kind, block_q=block_q, block_k=block_k, D=D, n_kv_heads=n_kv_heads
+    )
     pipelined = sum(
         int(np.prod(shape)) * _elem_bytes(elem, data_bytes)
         for part in ("in", "out")
@@ -184,6 +187,7 @@ def paged_vmem_findings(
     *,
     group: int,
     page_size: int,
+    n_kv_heads: int,
     D: int,
     data_bytes: int,
     subject: str,
@@ -191,14 +195,15 @@ def paged_vmem_findings(
 ):
     """KERN-VMEM for the fused paged-decode kernel.
 
-    Its per-grid-step working set streams the whole GQA query group against
-    one pool page — ``block_q`` maps to the group width, ``block_k`` to the
-    page size — and the scratch is the ``(group, D)`` float32 accumulator
-    plus two lane-replicated ``(group, MXU_LANE)`` m/l rows.
+    Its per-grid-step working set streams every KV head's GQA query group
+    against one whole pool page — ``block_q`` maps to the group width,
+    ``block_k`` to the page size — and the scratch is the
+    ``(Hkv, group, D)`` float32 accumulator plus two lane-replicated
+    ``(Hkv, group, MXU_LANE)`` m/l rows.
     """
     est = vmem_estimate(
         "paged_decode", block_q=group, block_k=page_size, D=D,
-        data_bytes=data_bytes,
+        data_bytes=data_bytes, n_kv_heads=n_kv_heads,
     )
     if est <= budget:
         return []
@@ -207,8 +212,8 @@ def paged_vmem_findings(
             "KERN-VMEM",
             subject,
             f"paged_decode kernel at group={group}, page_size={page_size}, "
-            f"D={D}, {data_bytes}-byte data needs ~{est / 2**20:.1f} MiB "
-            f"VMEM (budget {budget / 2**20:.0f} MiB)",
+            f"Hkv={n_kv_heads}, D={D}, {data_bytes}-byte data needs "
+            f"~{est / 2**20:.1f} MiB VMEM (budget {budget / 2**20:.0f} MiB)",
         )
     ]
 
@@ -320,6 +325,7 @@ def lint_paged_decode_config(
     *,
     group: int,
     page_size: int,
+    n_kv_heads: int,
     n_pages: int,
     table_width: int,
     D: int,
@@ -334,8 +340,8 @@ def lint_paged_decode_config(
     tail unmapped at the sentinel, plus one deliberately corrupt entry.
     """
     findings = paged_vmem_findings(
-        group=group, page_size=page_size, D=D, data_bytes=data_bytes,
-        subject=subject,
+        group=group, page_size=page_size, n_kv_heads=n_kv_heads, D=D,
+        data_bytes=data_bytes, subject=subject,
     )
     bt = np.full((1, table_width), n_pages, np.int32)
     used = min(table_width, n_pages)

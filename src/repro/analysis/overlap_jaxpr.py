@@ -29,10 +29,8 @@ __all__ = ["jaxpr_overlap_report", "trace_strategy", "overlap_findings"]
 
 def _closed_subjaxprs(eqn):
     """All sub-jaxprs hiding in an eqn's params (scan/pjit/custom_vjp/...)."""
-    import jax.core as jcore
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
-    ClosedJaxpr = jcore.ClosedJaxpr
-    Jaxpr = jcore.Jaxpr
     found = []
 
     def visit(v):
@@ -70,13 +68,13 @@ def _contains_dot(jaxpr, _memo=None) -> bool:
 
 def _analyze_context(jaxpr, name: str, rows: dict) -> None:
     """Taint-walk one computation context; recurse into scan bodies."""
-    import jax.core as jcore
+    from jax.extend.core import Literal
 
     tainted: set = set()
     permutes = 0
     blocked = 0
     for eqn in jaxpr.eqns:
-        in_vars = [v for v in eqn.invars if not isinstance(v, jcore.Literal)]
+        in_vars = [v for v in eqn.invars if not isinstance(v, Literal)]
         dirty = any(v in tainted for v in in_vars)
         prim = eqn.primitive.name
         if prim == "scan":

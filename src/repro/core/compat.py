@@ -1,74 +1,39 @@
-"""JAX version-compat shims (0.4.x <-> >=0.5).
+"""Mesh and shard_map constructors with the axis types the framework needs.
 
-The framework targets the current JAX API surface but must run on 0.4.x
-containers.  Every version-dependent symbol is resolved here, once, so the
-rest of the codebase imports from ``repro.core.compat`` and stays clean:
+JAX's own ``jax.make_mesh`` defaults every axis to ``AxisType.Explicit``,
+under which gathers with a sharded index operand raise ``ShardingTypeError``
+(the paged KV cache's block-table gathers among them).  The framework's
+sharding rules are written for ``Auto`` axes, so every mesh is built here:
 
-  * ``shard_map``  — ``jax.shard_map`` (>=0.5, ``check_vma=``) vs
-    ``jax.experimental.shard_map.shard_map`` (0.4.x, ``check_rep=``).
-  * ``make_mesh``  — ``jax.make_mesh`` with ``axis_types=`` dropped on
-    versions whose ``Mesh`` predates ``jax.sharding.AxisType``.
+  * ``make_mesh``   — ``jax.make_mesh`` with ``Auto`` axes.
   * ``device_mesh`` — ``jax.sharding.Mesh`` from an explicit device array,
-    likewise hiding the ``axis_types`` difference.
+    ``Auto`` axes.
+  * ``shard_map``   — ``jax.shard_map`` with the replication check off by
+    default (``check_vma=False``).
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType, Mesh
 
-__all__ = ["shard_map", "make_mesh", "device_mesh", "HAS_AXIS_TYPES"]
-
-try:  # >=0.5: AxisType exists and make_mesh/Mesh accept axis_types
-    from jax.sharding import AxisType as _AxisType
-
-    HAS_AXIS_TYPES = True
-except ImportError:  # 0.4.x
-    _AxisType = None
-    HAS_AXIS_TYPES = False
+__all__ = ["shard_map", "make_mesh", "device_mesh"]
 
 
-if hasattr(jax, "shard_map"):
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-        # 0.4.x spells the replication check ``check_rep``.
-        return _shard_map_04(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
-
-
-def _auto_axis_types(n):
-    if not HAS_AXIS_TYPES:
-        return None
-    return (_AxisType.Auto,) * n
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma,
+    )
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    kw = {} if devices is None else {"devices": devices}
-    types = _auto_axis_types(len(axis_names))
-    if types is not None:
-        try:
-            return jax.make_mesh(axis_shapes, axis_names, axis_types=types, **kw)
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(axis_shapes, axis_names, **kw)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        axis_shapes, axis_names, axis_types=(AxisType.Auto,) * len(axis_names),
+        devices=devices,
+    )
 
 
 def device_mesh(device_array, axis_names):
-    """``jax.sharding.Mesh`` from an explicit device ndarray (version-safe)."""
-    from jax.sharding import Mesh
-
-    types = _auto_axis_types(len(axis_names))
-    if types is not None:
-        return Mesh(device_array, axis_names, axis_types=types)
-    return Mesh(device_array, axis_names)
+    """``jax.sharding.Mesh`` from an explicit device ndarray, Auto axes."""
+    return Mesh(device_array, axis_names, axis_types=(AxisType.Auto,) * len(axis_names))

@@ -9,6 +9,14 @@ recompute kernels that make *training* under TokenRing live at kernel speed
 TPU-native design decisions (vs the CUDA FlashAttention-2 the paper calls):
   * Tiling is expressed through ``BlockSpec``s: HBM->VMEM movement is done by
     the Mosaic pipeline, not hand-rolled ``cp.async`` as on GPU.
+  * The kernels work head-major: q/k/v are ``(B, H, S, D)`` so every block
+    is ``(None, None, block, D)`` — a squeezed batch and head, and a
+    ``(block, D)`` tile whose last two dims satisfy Mosaic's (8, 128) rule.
+    Positions enter as ``(B, 1, S)`` and the per-row lse/delta/dlse as
+    ``(B, H, 1, S)``, so their blocks are ``(1, block)`` rows (a ``(1, bq, 1)``
+    block on a token-major ``(B, S, H)`` array is refused by the TPU
+    compiler).  The ``ops`` wrappers keep the public ``(B, S, H, D)`` layout
+    and transpose in and out.
   * Forward grid is ``(B, Hq, num_q_blocks, num_kv_blocks)`` with the KV
     dimension marked ``arbitrary`` (sequential): the online-softmax state for
     one (b, h, q-block) lives in VMEM scratch across consecutive KV-grid
@@ -56,9 +64,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# Renamed TPUCompilerParams -> CompilerParams across JAX versions.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 __all__ = [
     "flash_attention_fwd_pallas",
@@ -114,49 +119,52 @@ tile_skip = _tile_skip
 tile_mask = _tile_mask
 
 
-def kernel_buffer_shapes(kind: str, *, block_q: int, block_k: int, D: int):
+def kernel_buffer_shapes(
+    kind: str, *, block_q: int, block_k: int, D: int, n_kv_heads: int = 1
+):
     """Per-grid-step VMEM buffer shapes of one kernel, for footprint lints.
 
     ``kind`` is ``"fwd"``, ``"bwd_dq"``, ``"bwd_dkv"`` or ``"paged_decode"``.
     Returns ``{"in": [...], "out": [...], "scratch": [...]}`` where each entry
     is ``(shape, elem)`` with ``elem`` one of ``"data"`` (the q/k/v dtype),
-    ``"f32"`` or ``"i32"``.  These mirror the BlockSpecs and scratch_shapes
-    of the ``pallas_call``s below and in ``paged_attention.py`` — update both
-    together.  For ``"paged_decode"``, ``block_q`` is the GQA query-head
-    group streamed per KV head and ``block_k`` is the page size (one pool
-    page per sequential grid step).
+    ``"f32"`` or ``"i32"``.  Squeezed (``None``) block dims are left out.
+    These mirror the BlockSpecs and scratch_shapes of the ``pallas_call``s
+    below and in ``paged_attention.py`` — update both together.  For
+    ``"paged_decode"``, ``block_q`` is the GQA query-head group, ``block_k``
+    the page size, and ``n_kv_heads`` the KV heads of the one whole pool page
+    each sequential grid step takes.
     """
     bq, bk = block_q, block_k
     if kind == "paged_decode":
+        hg = (n_kv_heads, bq)
         return {
-            "in": [((1, 1, bq, D), "data"), ((1, bk, 1, D), "data"),
-                   ((1, bk, 1, D), "data"), ((1, bk), "i32")],
-            "out": [((1, 1, bq, D), "data"), ((1, 1, bq), "f32")],
-            "scratch": [((bq, D), "f32"), ((bq, MXU_LANE), "f32"),
-                        ((bq, MXU_LANE), "f32")],
+            "in": [(hg + (D,), "data"), ((bk, n_kv_heads, D), "data"),
+                   ((bk, n_kv_heads, D), "data"), ((1, bk), "i32")],
+            "out": [(hg + (D,), "data"), (hg + (MXU_LANE,), "f32")],
+            "scratch": [(hg + (D,), "f32"), (hg + (MXU_LANE,), "f32"),
+                        (hg + (MXU_LANE,), "f32")],
         }
     pos = [((1, bq), "i32"), ((1, bk), "i32")]
-    qkv = [((1, bq, 1, D), "data"), ((1, bk, 1, D), "data"),
-           ((1, bk, 1, D), "data")]
+    qkv = [((bq, D), "data"), ((bk, D), "data"), ((bk, D), "data")]
     if kind == "fwd":
         return {
             "in": pos + qkv,
-            "out": [((1, bq, 1, D), "data"), ((1, bq, 1), "f32")],
+            "out": [((bq, D), "data"), ((1, bq), "f32")],
             "scratch": [((bq, D), "f32"), ((bq, MXU_LANE), "f32"),
                         ((bq, MXU_LANE), "f32")],
         }
-    rows = [((1, bq, 1), "f32")] * 3  # lse, delta, dlse
-    bwd_in = pos + qkv + [((1, bq, 1, D), "data")] + rows  # + dout
+    rows = [((1, bq), "f32")] * 3  # lse, delta, dlse
+    bwd_in = pos + qkv + [((bq, D), "data")] + rows  # + dout
     if kind == "bwd_dq":
         return {
             "in": bwd_in,
-            "out": [((1, bq, 1, D), "f32")],
+            "out": [((bq, D), "f32")],
             "scratch": [((bq, D), "f32")],
         }
     if kind == "bwd_dkv":
         return {
             "in": bwd_in,
-            "out": [((1, bk, 1, D), "f32")] * 2,
+            "out": [((bk, D), "f32")] * 2,
             "scratch": [((bk, D), "f32")] * 2,
         }
     raise ValueError(f"unknown kernel kind {kind!r}")
@@ -166,11 +174,11 @@ def _fwd_kernel(
     # per-batch position arrays are regular VMEM refs here (see BlockSpecs)
     q_pos_ref,  # (1, block_q)      int32  global positions of this q tile
     k_pos_ref,  # (1, block_k)      int32  global positions of this kv tile
-    q_ref,  # (1, block_q, 1, D) in q.dtype
-    k_ref,  # (1, block_k, 1, D)
-    v_ref,  # (1, block_k, 1, D)
-    out_ref,  # (1, block_q, 1, D)
-    lse_ref,  # (1, block_q, 1)    float32
+    q_ref,  # (block_q, D) in q.dtype
+    k_ref,  # (block_k, D)
+    v_ref,  # (block_k, D)
+    out_ref,  # (block_q, D)
+    lse_ref,  # (1, block_q)       float32
     acc_ref,  # VMEM scratch (block_q, D)        float32
     m_ref,  # VMEM scratch (block_q, MXU_LANE) float32 (lane-replicated)
     l_ref,  # VMEM scratch (block_q, MXU_LANE) float32
@@ -197,9 +205,9 @@ def _fwd_kernel(
 
     @pl.when(jnp.logical_not(skip))
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale  # (bq, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)  # (bk, D)
+        q = q_ref[...].astype(jnp.float32) * scale  # (bq, D)
+        k = k_ref[...].astype(jnp.float32)  # (bk, D)
+        v = v_ref[...].astype(jnp.float32)  # (bk, D)
         scores = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (bq, bk)
@@ -236,9 +244,9 @@ def _fwd_kernel(
         denom = jnp.where(valid, l, 1.0)
         out = acc_ref[...] / denom[:, None]
         out = jnp.where(valid[:, None], out, 0.0)
-        out_ref[0, :, 0, :] = out.astype(out_ref.dtype)
+        out_ref[...] = out.astype(out_ref.dtype)
         lse = jnp.where(valid, m + jnp.log(denom), -jnp.inf)
-        lse_ref[0, :, 0] = lse
+        lse_ref[0, :] = lse
 
 
 def flash_attention_fwd_pallas(
@@ -255,16 +263,16 @@ def flash_attention_fwd_pallas(
     block_k: int = 512,
     interpret: bool = False,
 ):
-    """Pallas flash-attention forward.
+    """Pallas flash-attention forward, head-major.
 
-    Shapes: ``q (B,Sq,Hq,D)``, ``k/v (B,Sk,Hkv,D)``, ``q_pos (B,Sq) int32``,
+    Shapes: ``q (B,Hq,Sq,D)``, ``k/v (B,Hkv,Sk,D)``, ``q_pos (B,Sq) int32``,
     ``k_pos (B,Sk) int32`` (per-batch positions enable continuous-batching
     decode).  ``Sq % block_q == 0`` and ``Sk % block_k == 0`` must hold (the
-    ``ops`` wrapper pads).  Returns ``(out, lse)`` with ``out (B,Sq,Hq,D)`` in
-    q.dtype and ``lse (B,Sq,Hq)`` float32.
+    ``ops`` wrapper picks dividing blocks).  Returns ``(out, lse)`` with
+    ``out (B,Hq,Sq,D)`` in q.dtype and ``lse (B,Hq,Sq)`` float32.
     """
-    B, Sq, Hq, D = q.shape
-    _, Sk, Hkv, Dk = k.shape
+    B, Hq, Sq, D = q.shape
+    _, Hkv, Sk, Dk = k.shape
     assert Dk == D and v.shape == k.shape
     assert Hq % Hkv == 0, (Hq, Hkv)
     group = Hq // Hkv
@@ -285,23 +293,23 @@ def flash_attention_fwd_pallas(
 
     grid = (B, Hq, nq, nk)
     out_shape = [
-        jax.ShapeDtypeStruct((B, Sq, Hq, D), q.dtype),
-        jax.ShapeDtypeStruct((B, Sq, Hq), jnp.float32),
+        jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
+        jax.ShapeDtypeStruct((B, Hq, 1, Sq), jnp.float32),
     ]
+    q_spec = pl.BlockSpec((None, None, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0))
+    kv_spec = pl.BlockSpec(
+        (None, None, block_k, D), lambda b, h, iq, ik: (b, h // group, ik, 0)
+    )
     in_specs = [
-        pl.BlockSpec((1, block_q), lambda b, h, iq, ik: (b, iq)),  # q_pos
-        pl.BlockSpec((1, block_k), lambda b, h, iq, ik: (b, ik)),  # k_pos
-        pl.BlockSpec((1, block_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),  # q
-        pl.BlockSpec(
-            (1, block_k, 1, D), lambda b, h, iq, ik: (b, ik, h // group, 0)
-        ),  # k
-        pl.BlockSpec(
-            (1, block_k, 1, D), lambda b, h, iq, ik: (b, ik, h // group, 0)
-        ),  # v
+        pl.BlockSpec((None, 1, block_q), lambda b, h, iq, ik: (b, 0, iq)),  # q_pos
+        pl.BlockSpec((None, 1, block_k), lambda b, h, iq, ik: (b, 0, ik)),  # k_pos
+        q_spec,
+        kv_spec,  # k
+        kv_spec,  # v
     ]
     out_specs = [
-        pl.BlockSpec((1, block_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-        pl.BlockSpec((1, block_q, 1), lambda b, h, iq, ik: (b, iq, h)),
+        q_spec,
+        pl.BlockSpec((None, None, 1, block_q), lambda b, h, iq, ik: (b, h, 0, iq)),
     ]
     scratch_shapes = [
         pltpu.VMEM((block_q, D), jnp.float32),
@@ -316,13 +324,13 @@ def flash_attention_fwd_pallas(
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch_shapes,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )
-    out, lse = call(q_pos, k_pos, q, k, v)
-    return out, lse
+    out, lse = call(q_pos.reshape(B, 1, Sq), k_pos.reshape(B, 1, Sk), q, k, v)
+    return out, lse.reshape(B, Hq, Sq)
 
 
 # ---------------------------------------------------------------------------
@@ -367,14 +375,14 @@ def _bwd_p_ds(q, k, v, dout, lse, delta, dlse, q_pos, k_pos, *,
 def _bwd_dq_kernel(
     q_pos_ref,  # (1, block_q) int32
     k_pos_ref,  # (1, block_k) int32
-    q_ref,  # (1, block_q, 1, D)
-    k_ref,  # (1, block_k, 1, D)   KV head = query head // group
-    v_ref,  # (1, block_k, 1, D)
-    dout_ref,  # (1, block_q, 1, D)
-    lse_ref,  # (1, block_q, 1) float32
-    delta_ref,  # (1, block_q, 1) float32  rowsum(dout * out)
-    dlse_ref,  # (1, block_q, 1) float32
-    dq_ref,  # (1, block_q, 1, D) float32 out
+    q_ref,  # (block_q, D)
+    k_ref,  # (block_k, D)   KV head = query head // group
+    v_ref,  # (block_k, D)
+    dout_ref,  # (block_q, D)
+    lse_ref,  # (1, block_q) float32
+    delta_ref,  # (1, block_q) float32  rowsum(dout * out)
+    dlse_ref,  # (1, block_q) float32
+    dq_ref,  # (block_q, D) float32 out
     dq_acc_ref,  # VMEM scratch (block_q, D) float32
     *,
     causal: bool,
@@ -394,13 +402,11 @@ def _bwd_dq_kernel(
 
     @pl.when(jnp.logical_not(skip))
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        dout = dout_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[...].astype(jnp.float32)
         _, ds = _bwd_p_ds(
-            q, k, v, dout, lse_ref[0, :, 0], delta_ref[0, :, 0],
-            dlse_ref[0, :, 0], q_pos, k_pos, causal=causal, window=window,
+            q_ref[...].astype(jnp.float32), k, v_ref[...].astype(jnp.float32),
+            dout_ref[...].astype(jnp.float32), lse_ref[0, :], delta_ref[0, :],
+            dlse_ref[0, :], q_pos, k_pos, causal=causal, window=window,
             scale=scale,
         )
         dq_acc_ref[...] += jax.lax.dot_general(
@@ -409,21 +415,21 @@ def _bwd_dq_kernel(
 
     @pl.when(ik == num_kv_blocks - 1)
     def _finalize():
-        dq_ref[0, :, 0, :] = dq_acc_ref[...]
+        dq_ref[...] = dq_acc_ref[...]
 
 
 def _bwd_dkv_kernel(
     q_pos_ref,  # (1, block_q) int32
     k_pos_ref,  # (1, block_k) int32
-    q_ref,  # (1, block_q, 1, D)   query head = h_kv * group + g
-    k_ref,  # (1, block_k, 1, D)
-    v_ref,  # (1, block_k, 1, D)
-    dout_ref,  # (1, block_q, 1, D)
-    lse_ref,  # (1, block_q, 1) float32
-    delta_ref,  # (1, block_q, 1) float32
-    dlse_ref,  # (1, block_q, 1) float32
-    dk_ref,  # (1, block_k, 1, D) float32 out
-    dv_ref,  # (1, block_k, 1, D) float32 out
+    q_ref,  # (block_q, D)   query head = h_kv * group + g
+    k_ref,  # (block_k, D)
+    v_ref,  # (block_k, D)
+    dout_ref,  # (block_q, D)
+    lse_ref,  # (1, block_q) float32
+    delta_ref,  # (1, block_q) float32
+    dlse_ref,  # (1, block_q) float32
+    dk_ref,  # (block_k, D) float32 out
+    dv_ref,  # (block_k, D) float32 out
     dk_acc_ref,  # VMEM scratch (block_k, D) float32
     dv_acc_ref,  # VMEM scratch (block_k, D) float32
     *,
@@ -451,14 +457,12 @@ def _bwd_dkv_kernel(
 
     @pl.when(jnp.logical_not(skip))
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        dout = dout_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)
+        dout = dout_ref[...].astype(jnp.float32)
         p, ds = _bwd_p_ds(
-            q, k, v, dout, lse_ref[0, :, 0], delta_ref[0, :, 0],
-            dlse_ref[0, :, 0], q_pos, k_pos, causal=causal, window=window,
-            scale=scale,
+            q, k_ref[...].astype(jnp.float32), v_ref[...].astype(jnp.float32),
+            dout, lse_ref[0, :], delta_ref[0, :], dlse_ref[0, :], q_pos,
+            k_pos, causal=causal, window=window, scale=scale,
         )
         dv_acc_ref[...] += jax.lax.dot_general(
             p, dout, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -469,8 +473,8 @@ def _bwd_dkv_kernel(
 
     @pl.when(inner == group * num_q_blocks - 1)
     def _finalize():
-        dk_ref[0, :, 0, :] = dk_acc_ref[...]
-        dv_ref[0, :, 0, :] = dv_acc_ref[...]
+        dk_ref[...] = dk_acc_ref[...]
+        dv_ref[...] = dv_acc_ref[...]
 
 
 def flash_attention_bwd_pallas(
@@ -493,15 +497,16 @@ def flash_attention_bwd_pallas(
 ):
     """Pallas flash-attention backward: returns ``(dq, dk, dv)`` in float32.
 
-    Shapes mirror the forward (``q (B,Sq,Hq,D)``, ``k/v (B,Sk,Hkv,D)``);
-    ``out``/``lse`` are the forward products (residuals), ``dout``/``dlse``
-    the cotangents.  Two pallas_calls: the dq grid parallelizes over
-    ``(B, Hq, q_blocks)`` with KV sequential; the dk/dv grid parallelizes
-    over ``(B, Hkv, kv_blocks)`` with ``(group, q_blocks)`` sequential so the
-    GQA group sum stays in VMEM scratch.
+    Head-major like the forward (``q/out/dout (B,Hq,Sq,D)``,
+    ``k/v (B,Hkv,Sk,D)``, ``lse/dlse (B,Hq,Sq)``); ``out``/``lse`` are the
+    forward products (residuals), ``dout``/``dlse`` the cotangents.  Two
+    pallas_calls: the dq grid parallelizes over ``(B, Hq, q_blocks)`` with
+    KV sequential; the dk/dv grid parallelizes over ``(B, Hkv, kv_blocks)``
+    with ``(group, q_blocks)`` sequential so the GQA group sum stays in VMEM
+    scratch.
     """
-    B, Sq, Hq, D = q.shape
-    _, Sk, Hkv, Dk = k.shape
+    B, Hq, Sq, D = q.shape
+    _, Hkv, Sk, Dk = k.shape
     assert Dk == D and v.shape == k.shape
     assert Hq % Hkv == 0, (Hq, Hkv)
     group = Hq // Hkv
@@ -512,12 +517,17 @@ def flash_attention_bwd_pallas(
     if scale is None:
         scale = 1.0 / (D**0.5)
 
-    doutf = dout.astype(jnp.float32)
-    delta = jnp.sum(doutf * out.astype(jnp.float32), axis=-1)  # (B,Sq,Hq)
-    lse = lse.astype(jnp.float32)
-    dlse = dlse.astype(jnp.float32)
+    # Per-row operands as (B, Hq, 1, Sq): their blocks are (1, block_q) rows.
+    delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    rows = [x.astype(jnp.float32).reshape(B, Hq, 1, Sq) for x in (lse, delta, dlse)]
+    q_pos = q_pos.reshape(B, 1, Sq)
+    k_pos = k_pos.reshape(B, 1, Sk)
 
-    row_spec = pl.BlockSpec((1, block_q, 1), lambda b, h, iq, ik: (b, iq, h))
+    row_spec = pl.BlockSpec((None, None, 1, block_q), lambda b, h, iq, ik: (b, h, 0, iq))
+    q_spec = pl.BlockSpec((None, None, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0))
+    kv_spec = pl.BlockSpec(
+        (None, None, block_k, D), lambda b, h, iq, ik: (b, h // group, ik, 0)
+    )
     dq_call = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, causal=causal, window=window, scale=float(scale),
@@ -525,40 +535,36 @@ def flash_attention_bwd_pallas(
         ),
         grid=(B, Hq, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda b, h, iq, ik: (b, iq)),  # q_pos
-            pl.BlockSpec((1, block_k), lambda b, h, iq, ik: (b, ik)),  # k_pos
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec(
-                (1, block_k, 1, D), lambda b, h, iq, ik: (b, ik, h // group, 0)
-            ),  # k
-            pl.BlockSpec(
-                (1, block_k, 1, D), lambda b, h, iq, ik: (b, ik, h // group, 0)
-            ),  # v
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
+            pl.BlockSpec((None, 1, block_q), lambda b, h, iq, ik: (b, 0, iq)),  # q_pos
+            pl.BlockSpec((None, 1, block_k), lambda b, h, iq, ik: (b, 0, ik)),  # k_pos
+            q_spec,  # q
+            kv_spec,  # k
+            kv_spec,  # v
+            q_spec,  # dout
             row_spec,  # lse
             row_spec,  # delta
             row_spec,  # dlse
         ],
-        out_specs=pl.BlockSpec(
-            (1, block_q, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, Hq, D), jnp.float32),
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )
-    dq = dq_call(q_pos, k_pos, q, k, v, dout, lse, delta, dlse)
+    dq = dq_call(q_pos, k_pos, q, k, v, dout, *rows)
 
     # dk/dv: query head streamed through the accumulator is h*group + g.
     qrow_spec = pl.BlockSpec(
-        (1, block_q, 1), lambda b, h, ik, g, iq: (b, iq, h * group + g)
+        (None, None, 1, block_q), lambda b, h, ik, g, iq: (b, h * group + g, 0, iq)
     )
     qhead_spec = pl.BlockSpec(
-        (1, block_q, 1, D), lambda b, h, ik, g, iq: (b, iq, h * group + g, 0)
+        (None, None, block_q, D), lambda b, h, ik, g, iq: (b, h * group + g, iq, 0)
     )
-    kv_spec = pl.BlockSpec((1, block_k, 1, D), lambda b, h, ik, g, iq: (b, ik, h, 0))
+    kv_spec = pl.BlockSpec(
+        (None, None, block_k, D), lambda b, h, ik, g, iq: (b, h, ik, 0)
+    )
     dkv_call = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, causal=causal, window=window, scale=float(scale),
@@ -566,8 +572,8 @@ def flash_attention_bwd_pallas(
         ),
         grid=(B, Hkv, nk, group, nq),
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda b, h, ik, g, iq: (b, iq)),  # q_pos
-            pl.BlockSpec((1, block_k), lambda b, h, ik, g, iq: (b, ik)),  # k_pos
+            pl.BlockSpec((None, 1, block_q), lambda b, h, ik, g, iq: (b, 0, iq)),  # q_pos
+            pl.BlockSpec((None, 1, block_k), lambda b, h, ik, g, iq: (b, 0, ik)),  # k_pos
             qhead_spec,  # q
             kv_spec,  # k
             kv_spec,  # v
@@ -578,19 +584,19 @@ def flash_attention_bwd_pallas(
         ],
         out_specs=[kv_spec, kv_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Sk, Hkv, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Sk, Hkv, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, Sk, D), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, Sk, D), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel", "parallel", "parallel", "arbitrary", "arbitrary",
             ),
         ),
         interpret=interpret,
     )
-    dk, dv = dkv_call(q_pos, k_pos, q, k, v, dout, lse, delta, dlse)
+    dk, dv = dkv_call(q_pos, k_pos, q, k, v, dout, *rows)
     return dq, dk, dv

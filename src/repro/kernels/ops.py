@@ -354,18 +354,29 @@ def _xla_flash_bwd(cfg: FlashConfig, q, k, v, q_pos, k_pos, out, lse, dout, dlse
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
+def _heads_major(x):
+    """``(B, S, H, D) <-> (B, H, S, D)``: the Pallas kernels run head-major
+    (their blocks must be ``(S-tile, D)``), the public API is token-major.
+    Each call moves O(S*H*D) bytes through HBM."""
+    return x.transpose(0, 2, 1, 3)
+
+
 def _flash_bwd(cfg: FlashConfig, q, k, v, q_pos, k_pos, out, lse, dout, dlse):
     impl = cfg.resolve_impl()
     if impl in ("pallas", "pallas_interpret"):
         Sq, Sk = q.shape[1], k.shape[1]
         dq, dk, dv = flash_attention_bwd_pallas(
-            q, k, v, q_pos, k_pos, out, lse, dout, dlse,
+            *map(_heads_major, (q, k, v)), q_pos, k_pos,
+            _heads_major(out), lse.transpose(0, 2, 1), _heads_major(dout),
+            dlse.transpose(0, 2, 1),
             causal=cfg.causal, window=cfg.window, scale=cfg.scale,
             block_q=_pick_block(Sq, cfg.bwd_block_q),
             block_k=_pick_block(Sk, cfg.bwd_block_k),
             interpret=impl == "pallas_interpret",
         )
-        return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+        return tuple(
+            _heads_major(g).astype(x.dtype) for g, x in ((dq, q), (dk, k), (dv, v))
+        )
     return _xla_flash_bwd(cfg, q, k, v, q_pos, k_pos, out, lse, dout, dlse)
 
 
@@ -379,23 +390,19 @@ def _flash(cfg: FlashConfig, q, k, v, q_pos, k_pos):
     impl = cfg.resolve_impl()
     if impl == "xla":
         return _xla_flash_fwd(cfg, q, k, v, q_pos, k_pos)
-    interpret = impl == "pallas_interpret"
     Sq, Sk = q.shape[1], k.shape[1]
-    bq = _pick_block(Sq, cfg.block_q)
-    bk = _pick_block(Sk, cfg.block_k)
-    return flash_attention_fwd_pallas(
-        q,
-        k,
-        v,
+    out, lse = flash_attention_fwd_pallas(
+        *map(_heads_major, (q, k, v)),
         q_pos,
         k_pos,
         causal=cfg.causal,
         window=cfg.window,
         scale=cfg.scale,
-        block_q=bq,
-        block_k=bk,
-        interpret=interpret,
+        block_q=_pick_block(Sq, cfg.block_q),
+        block_k=_pick_block(Sk, cfg.block_k),
+        interpret=impl == "pallas_interpret",
     )
+    return _heads_major(out), lse.transpose(0, 2, 1)
 
 
 def _flash_fwd_rule(cfg, q, k, v, q_pos, k_pos):

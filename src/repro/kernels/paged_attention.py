@@ -10,16 +10,21 @@ maps* read it to address the page pool directly, so the Mosaic pipeline
 streams exactly the pages a request maps — no dense view ever exists.
 
 Design (mirrors the PR-3 kernel family in ``flash_attention.py``):
-  * Grid is ``(B, Hkv, W)`` with ``W`` the block-table width (logical pages
-    per slot); the page dimension is sequential (``arbitrary``) so the
-    online-softmax state for one ``(b, h_kv)`` cell lives in VMEM scratch
-    across consecutive pages.  One grid step covers one physical page —
-    pages are non-contiguous in the pool, so a BlockSpec block cannot span
-    more than one.
-  * GQA/MQA: the whole query-head *group* for a KV head is streamed through
-    the accumulators at once — q/out blocks are ``(1, 1, group, D)`` and the
-    scratch is ``(group, D)`` (+ two ``(group, MXU_LANE)`` lane-replicated
-    m/l rows), so KV pages are fetched once per group, never per query head.
+  * Grid is ``(B, W)`` with ``W`` the block-table width (logical pages per
+    slot); the page dimension is sequential (``arbitrary``) so the
+    online-softmax state for one request lives in VMEM scratch across
+    consecutive pages.  One grid step takes one whole physical page,
+    ``(page_size, Hkv, D)`` — pages are non-contiguous in the pool, so a
+    BlockSpec block cannot span more than one, and a block that kept the
+    pool's trailing ``(Hkv, D)`` dims whole is the one the TPU compiler
+    accepts (a per-head ``(1, page_size, 1, D)`` block is refused: its last
+    two dims are neither (8, 128)-aligned nor the full array dims).  The body
+    loops over the page's KV heads.
+  * GQA/MQA: each KV head's whole query-head *group* is streamed through
+    the accumulators at once — the q/out blocks are ``(Hkv, group, D)`` and
+    the scratch is ``(Hkv, group, D)`` (+ two ``(Hkv, group, MXU_LANE)``
+    lane-replicated m/l rows), so each page is fetched once per request,
+    never per query head.
   * Unmapped block-table entries carry the sentinel ``n_pages``.  The index
     maps *clamp* the page id so the prefetch address stays in-bounds, while
     the kernel body reads the **raw** table entry and skips the whole step
@@ -53,9 +58,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.flash_attention import MXU_LANE, NEG_INF, PAD_POS
-
-# Renamed TPUCompilerParams -> CompilerParams across JAX versions.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 __all__ = [
     "paged_decode_fwd_pallas",
@@ -111,15 +113,15 @@ def _paged_decode_kernel(
     bt_ref,  # (B, W)  int32  block tables; sentinel == n_pages means unmapped
     qp_ref,  # (B, 1)  int32  query position (== used length) per request
     # pipelined VMEM refs
-    q_ref,  # (1, 1, group, D) q.dtype — the KV head's whole query group
-    k_ref,  # (1, page_size, 1, D)     — one physical pool page
-    v_ref,  # (1, page_size, 1, D)
-    pos_ref,  # (1, page_size) int32   — that page's global token positions
-    out_ref,  # (1, 1, group, D)
-    lse_ref,  # (1, 1, group) float32
-    acc_ref,  # VMEM scratch (group, D) float32
-    m_ref,  # VMEM scratch (group, MXU_LANE) float32 (lane-replicated)
-    l_ref,  # VMEM scratch (group, MXU_LANE) float32
+    q_ref,  # (Hkv, group, D) q.dtype — every KV head's query group
+    k_ref,  # (page_size, Hkv, D)     — one whole physical pool page
+    v_ref,  # (page_size, Hkv, D)
+    pos_ref,  # (1, page_size) int32  — that page's global token positions
+    out_ref,  # (Hkv, group, D)
+    lse_ref,  # (Hkv, group, MXU_LANE) float32 (lane-replicated)
+    acc_ref,  # VMEM scratch (Hkv, group, D) float32
+    m_ref,  # VMEM scratch (Hkv, group, MXU_LANE) float32 (lane-replicated)
+    l_ref,  # VMEM scratch (Hkv, group, MXU_LANE) float32
     *,
     n_pages: int,
     window: int | None,
@@ -127,7 +129,8 @@ def _paged_decode_kernel(
     num_pages_grid: int,
 ):
     b = pl.program_id(0)
-    ip = pl.program_id(2)
+    ip = pl.program_id(1)
+    n_kv_heads = k_ref.shape[1]
 
     @pl.when(ip == 0)
     def _init():
@@ -146,47 +149,45 @@ def _paged_decode_kernel(
 
     @pl.when(jnp.logical_not(skip))
     def _compute():
-        q = q_ref[0, 0, :, :].astype(jnp.float32) * scale  # (group, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (page_size, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)  # (page_size, D)
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (group, page_size)
-
-        # Per-element mask; every query row in the group shares the single
+        # Per-element mask; every query row of every group shares the single
         # decode position.
-        mask = page_mask(k_pos, q_pos, window=window)
-        scores = jnp.where(mask[None, :], scores, NEG_INF)
+        mask = page_mask(k_pos, q_pos, window=window)[None, :]
+        for h in range(n_kv_heads):  # static unroll over the page's KV heads
+            q = q_ref[h].astype(jnp.float32) * scale  # (group, D)
+            k = k_ref[:, h, :].astype(jnp.float32)  # (page_size, D)
+            v = v_ref[:, h, :].astype(jnp.float32)  # (page_size, D)
+            scores = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            )  # (group, page_size)
+            scores = jnp.where(mask, scores, NEG_INF)
 
-        m_prev = m_ref[:, 0]  # (group,)
-        l_prev = l_ref[:, 0]
-        m_cur = jnp.max(scores, axis=-1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        safe_m = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
-        p = jnp.exp(scores - safe_m[:, None])  # (group, page_size)
-        p = jnp.where(mask[None, :], p, 0.0)
-        alpha = jnp.exp(jnp.minimum(m_prev - safe_m, 0.0))
-        alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, alpha)
+            m_prev = m_ref[h][:, 0]  # (group,)
+            l_prev = l_ref[h][:, 0]
+            m_cur = jnp.max(scores, axis=-1)
+            m_new = jnp.maximum(m_prev, m_cur)
+            safe_m = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
+            p = jnp.exp(scores - safe_m[:, None])  # (group, page_size)
+            p = jnp.where(mask, p, 0.0)
+            alpha = jnp.exp(jnp.minimum(m_prev - safe_m, 0.0))
+            alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, alpha)
 
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1)
-        acc = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-        acc_ref[...] = acc
-        m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1)
+            acc_ref[h] = acc_ref[h] * alpha[:, None] + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            )
+            m_ref[h] = jnp.broadcast_to(m_new[:, None], m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new[:, None], l_ref.shape[1:])
 
     @pl.when(ip == num_pages_grid - 1)
     def _finalize():
-        m = m_ref[:, 0]
-        l = l_ref[:, 0]
+        m = m_ref[...]
+        l = l_ref[...]
         valid = l > 0.0
         denom = jnp.where(valid, l, 1.0)
-        out = acc_ref[...] / denom[:, None]
-        out = jnp.where(valid[:, None], out, 0.0)
-        out_ref[0, 0, :, :] = out.astype(out_ref.dtype)
-        lse_ref[0, 0, :] = jnp.where(valid, m + jnp.log(denom), -jnp.inf)
+        out = acc_ref[...] / denom[..., :1]
+        out = jnp.where(valid[..., :1], out, 0.0)
+        out_ref[...] = out.astype(out_ref.dtype)
+        lse_ref[...] = jnp.where(valid, m + jnp.log(denom), -jnp.inf)
 
 
 def paged_decode_fwd_pallas(
@@ -235,29 +236,30 @@ def paged_decode_fwd_pallas(
     # Index maps address the pool through the scalar-prefetched table.  The
     # clamp keeps the sentinel's prefetch in-bounds; the kernel body skips it
     # from the raw entry (see _paged_decode_kernel).
-    def _kv_map(b, h, ip, bt_ref, qp_ref):
-        return (page_index_clamp(bt_ref[b, ip], n_pages), 0, h, 0)
+    def _page_map(b, ip, bt_ref, qp_ref):
+        return (page_index_clamp(bt_ref[b, ip], n_pages), 0, 0, 0)
 
-    def _pos_map(b, h, ip, bt_ref, qp_ref):
-        return (page_index_clamp(bt_ref[b, ip], n_pages), 0)
+    def _pos_map(b, ip, bt_ref, qp_ref):
+        return (page_index_clamp(bt_ref[b, ip], n_pages), 0, 0)
 
+    heads = (None, Hkv, group)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block_tables, q_pos
-        grid=(B, Hkv, W),
+        grid=(B, W),
         in_specs=[
-            pl.BlockSpec((1, 1, group, D), lambda b, h, ip, *_: (b, 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, D), _kv_map),
-            pl.BlockSpec((1, page_size, 1, D), _kv_map),
-            pl.BlockSpec((1, page_size), _pos_map),
+            pl.BlockSpec(heads + (D,), lambda b, ip, *_: (b, 0, 0, 0)),
+            pl.BlockSpec((None, page_size, Hkv, D), _page_map),
+            pl.BlockSpec((None, page_size, Hkv, D), _page_map),
+            pl.BlockSpec((None, 1, page_size), _pos_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, group, D), lambda b, h, ip, *_: (b, 0, h, 0)),
-            pl.BlockSpec((1, 1, group), lambda b, h, ip, *_: (b, 0, h)),
+            pl.BlockSpec(heads + (D,), lambda b, ip, *_: (b, 0, 0, 0)),
+            pl.BlockSpec(heads + (MXU_LANE,), lambda b, ip, *_: (b, 0, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((group, D), jnp.float32),
-            pltpu.VMEM((group, MXU_LANE), jnp.float32),
-            pltpu.VMEM((group, MXU_LANE), jnp.float32),
+            pltpu.VMEM((Hkv, group, D), jnp.float32),
+            pltpu.VMEM((Hkv, group, MXU_LANE), jnp.float32),
+            pltpu.VMEM((Hkv, group, MXU_LANE), jnp.float32),
         ],
     )
 
@@ -265,12 +267,15 @@ def paged_decode_fwd_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, 1, Hq, D), q.dtype),
-            jax.ShapeDtypeStruct((B, 1, Hq), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
+            jax.ShapeDtypeStruct((B, Hkv, group, MXU_LANE), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(bt, qp, q, k_pool, v_pool, pos_pool)
-    return out, lse
+    )(
+        bt, qp, q.reshape(B, Hkv, group, D), k_pool, v_pool,
+        pos_pool.reshape(n_pages, 1, page_size),
+    )
+    return out.reshape(B, 1, Hq, D), lse[..., 0].reshape(B, 1, Hq)

@@ -109,12 +109,12 @@ def analyze_kernels(report: Report) -> None:
                 for D_k in (64, 128):
                     subject = (
                         f"PagedDecode(group={group}, page={page_size}, "
-                        f"D={D_k}, {data_bytes}B)"
+                        f"Hkv=8, D={D_k}, {data_bytes}B)"
                     )
                     report.extend(lint_paged_decode_config(
-                        group=group, page_size=page_size, n_pages=64,
-                        table_width=8, D=D_k, data_bytes=data_bytes,
-                        window=WINDOW, subject=subject,
+                        group=group, page_size=page_size, n_kv_heads=8,
+                        n_pages=64, table_width=8, D=D_k,
+                        data_bytes=data_bytes, window=WINDOW, subject=subject,
                     ))
                     report.note_checked("kernel")
     # Tile-skip soundness over the layouts the strategies actually produce.

@@ -53,6 +53,11 @@ _WHILE_RE = re.compile(
     r"while\(.*?\).*?body=%?([\w.\-]+).*?known_trip_count\":\{\"n\":\"(\d+)\"",
     re.DOTALL,
 )
+# TPU HLO text carries no known_trip_count: the trip count is read off the
+# loop's condition computation instead (see _cond_trip_count).
+_WHILE_COND_RE = re.compile(
+    r"\bwhile\(.*?condition=%?([\w.\-]+), body=%?([\w.\-]+)"
+)
 _CALLS_RE = re.compile(
     r"(?:body|condition|to_apply|branch_computations=\{)[=%]?%?([\w.\-]+)"
 )
@@ -139,6 +144,15 @@ def _split_computations(hlo: str):
     return comps
 
 
+def _cond_trip_count(lines) -> int:
+    """Trip count of a ``lax.scan``/``fori_loop`` while loop from its
+    condition computation: JAX's counted loops run ``i = 0; i < N`` with
+    ``N`` the condition's only integer scalar constant.  1 when the
+    condition is not of that form."""
+    consts = re.findall(r"[su]32\[\][^=]*?constant\((\d+)\)", "\n".join(lines))
+    return int(consts[0]) if len(consts) == 1 else 1
+
+
 def _multipliers(comps):
     """computation name -> execution count (product of enclosing trip counts)."""
     # map computation -> (child computation, trip) for while bodies; and
@@ -156,6 +170,11 @@ def _multipliers(comps):
                 body, n = wm.group(1), int(wm.group(2))
                 edges[name].append((body, n))
                 # condition executes n+1 times but holds no collectives/dots
+                continue
+            wc = _WHILE_COND_RE.search(ln)
+            if wc:
+                cond, body = wc.groups()
+                edges[name].append((body, _cond_trip_count(comps.get(cond, []))))
                 continue
             for cm in re.finditer(r"(?:body|condition|to_apply)=%?([\w.\-]+)", ln):
                 child = cm.group(1)
@@ -434,14 +453,23 @@ def analyze_hlo(hlo: str, *, world: int, ring_sizes: dict | None = None) -> HloS
             if kind is None:
                 # also catch '%all-reduce.1 = ... all-reduce(' patterns
                 kind = next((c for c in _COLLECTIVES if re.search(rf"\b{c}[.\d]*\(", ln)), None)
+            # TPU splits a permute into an async start/done pair; the start's
+            # tuple type leads with the operand buffer (the bytes sent).
+            async_start = kind is None and " collective-permute-start(" in ln
+            if async_start:
+                kind = "collective-permute"
             if kind is None:
                 continue
             dm = _DEF_RE.match(ln)
             if not dm:
                 continue
-            nbytes = _shape_bytes(dm.group(2).split(" ", 1)[0]) or _shape_bytes(
-                dm.group(2)
-            )
+            if async_start:
+                first = _SHAPE_RE.search(dm.group(2))
+                nbytes = _shape_bytes(first.group(0)) if first else 0
+            else:
+                nbytes = _shape_bytes(dm.group(2).split(" ", 1)[0]) or _shape_bytes(
+                    dm.group(2)
+                )
             stats.n_collectives += 1
             stats.collective_bytes[kind] += m * nbytes
 

@@ -37,6 +37,7 @@ import numpy as np
 from repro.configs import ARCHS
 from repro.core.api import ParallelContext
 from repro.core.strategies import get_strategy, strategy_cost
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving.engine import ServingEngine
 
@@ -113,6 +114,8 @@ def print_adaptive_prefill(cfg, *, max_len: int, sp_degree: int = 4,
 
 
 def main(argv=None):
+    """Serve ``--requests`` synthetic requests; returns ``(stats, finished
+    requests)`` — each request carries its generated ``output`` tokens."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b")
     ap.add_argument("--reduced", action="store_true")
@@ -182,6 +185,7 @@ def main(argv=None):
                     help="snapshot the engine every N ticks while requests "
                     "are in flight (needs --snapshot-dir)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = ARCHS[args.arch]
     if args.reduced:
@@ -265,8 +269,10 @@ def main(argv=None):
             f"({st['straggler_events']} straggler events)"
         )
     for r in done[:3]:
-        print(f"  req {r.uid}: prompt {r.prompt.tolist()} -> {r.output}")
-    return s
+        tail = r.prompt[-8:].tolist()
+        print(f"  req {r.uid}: prompt of {len(r.prompt)} tokens, ending "
+              f"{tail} -> {r.output}")
+    return s, done
 
 
 if __name__ == "__main__":

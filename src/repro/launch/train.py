@@ -21,6 +21,7 @@ from repro.configs import ARCHS
 from repro.core.api import ParallelContext
 from repro.core.strategies import available_strategies, get_strategy
 from repro.data.synthetic import SyntheticConfig, SyntheticDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.optim.adamw import AdamWConfig
 from repro.runtime.fault_tolerance import FailureInjector, FaultTolerantRunner
@@ -71,6 +72,7 @@ def main(argv=None):
         help="backward dq/dkv kernel KV tile (default: --block-k)",
     )
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = ARCHS[args.arch]
     if args.reduced:

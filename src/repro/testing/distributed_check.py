@@ -19,13 +19,13 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro.core.compat import shard_map  # noqa: E402
+from repro.core.compat import make_mesh, shard_map  # noqa: E402
 
 
 def check_compressed_psum():
     from repro.optim.compress import compressed_psum_ef
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     rng = np.random.default_rng(0)
     g_all = jnp.asarray(rng.standard_normal((8, 64)), jnp.float32)  # per-device rows
     exact_mean = np.asarray(g_all).mean(axis=0)

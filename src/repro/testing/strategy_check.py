@@ -25,6 +25,7 @@ import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from repro.core import ParallelContext, sp_attention, sp_decode, sp_scan  # noqa: E402
+from repro.core.compat import make_mesh  # noqa: E402
 from repro.core.zigzag import to_zigzag  # noqa: E402
 from repro.kernels.flash_attention import PAD_POS  # noqa: E402
 from repro.kernels.ref import attention_reference  # noqa: E402
@@ -55,7 +56,7 @@ def check_strategies():
     from repro.core.strategies import ineligible_reason, registered_strategies
 
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev // 4, 4), ("data", "model"))
+    mesh = make_mesh((n_dev // 4, 4), ("data", "model"))
     for desc in registered_strategies():
         for layout, causal, (Hq, Hkv) in [
             ("zigzag", True, (4, 4)),
@@ -96,7 +97,7 @@ def check_gradients():
 
     n_dev = len(jax.devices())
     P_sp = 4
-    mesh = jax.make_mesh((n_dev // P_sp, P_sp), ("data", "model"))
+    mesh = make_mesh((n_dev // P_sp, P_sp), ("data", "model"))
     Hq, Hkv, W = 8, 4, 96
     q, k, v = _data(Hq=Hq, Hkv=Hkv, seed=7)
     S = q.shape[1]
@@ -155,7 +156,7 @@ def check_gradients():
 
 
 def check_hybrid():
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     # ulysses as hybrid inner: head divisibility is judged at the intra-pod
     # degree (2), not the total SP degree (4) — Hkv=2 % 2 == 0 is legal.
     for inner in ["tokenring", "ring", "ulysses"]:
@@ -209,7 +210,7 @@ def check_hybrid():
 
 
 def check_decode():
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     pctx = ParallelContext(mesh=mesh, sp_axes=("model",), impl="xla", block_k=32)
     B, Skv, Hq, Hkv, D = 2, 256, 8, 2, 32
     rng = np.random.default_rng(13)
@@ -239,7 +240,7 @@ def check_prefill_chunk():
     single-device oracle over the full visible prefix."""
     from repro.core import sp_prefill
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     pctx = ParallelContext(mesh=mesh, sp_axes=("model",), impl="xla", block_k=32)
     B, Smax, C, Hq, Hkv, D = 2, 256, 16, 8, 2, 32
     filled = 96  # cache slots already holding previous chunks
@@ -327,7 +328,7 @@ def check_paged():
     # interpreter mode (impl=pallas_interpret): each shard runs the kernel
     # over its contiguous pool stripe via the remapped block table, merged
     # by the same psum lse-merge.
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
 
     def run_chain(impl):
         pctx = ParallelContext(
@@ -379,7 +380,7 @@ def check_paged():
 
 
 def check_scan():
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     pctx = ParallelContext(mesh=mesh, sp_axes=("model",), layout="contig")
     B, S, Dst = 2, 64, 8
     rng = np.random.default_rng(17)
@@ -399,7 +400,7 @@ def check_scan():
 
 
 def check_scan_hybrid():
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     pctx = ParallelContext(mesh=mesh, sp_axes=("pod", "model"), layout="contig")
     B, S, Dst = 2, 32, 4
     rng = np.random.default_rng(19)
@@ -435,7 +436,7 @@ def check_moe():
     dense_pctx = ParallelContext(mesh=None)
     y_ref, aux_ref = jax.jit(lambda p, x: moe_ffn(p, x, cfg, dense_pctx))(p, x)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     pctx = ParallelContext(mesh=mesh, sp_axes=("model",), impl="xla")
     y, aux = jax.jit(lambda p, x: moe_ffn(p, x, cfg, pctx))(p, x)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=2e-4, rtol=2e-4)
@@ -481,7 +482,7 @@ def check_sharded_ce():
         )
     )(x, w)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     pctx = ParallelContext(mesh=mesh, sp_axes=("model",), impl="xla")
     got, gotn = jax.jit(
         lambda x, w: chunked_cross_entropy(
@@ -518,7 +519,7 @@ def check_sharded_ce():
 
 def check_travel_dtype():
     """TokenRing with bf16 accumulator wire: same result within bf16 tol."""
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     q, k, v = _data(Hq=4, Hkv=4, seed=31)
     S = q.shape[1]
     ref, _ = attention_reference(q, k, v, causal=True)
@@ -541,7 +542,7 @@ def check_window():
     the planner routes windowed layers to it from any configured strategy."""
     from repro.core.api import AttnShapes
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     B, S, Hq, Hkv, D, W = 2, 256, 4, 2, 32, 96
     rng = np.random.default_rng(37)
     q = jnp.asarray(rng.standard_normal((B, S, Hq, D)), jnp.float32)
@@ -587,7 +588,7 @@ def check_overlap():
     from repro.launch.hlo_analysis import analyze_hlo, overlap_report
 
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev // 4, 4), ("data", "model"))
+    mesh = make_mesh((n_dev // 4, 4), ("data", "model"))
     B, S, Hq, Hkv, D = 2, 256, 4, 4, 32
     q, k, v = _data(B=B, S=S, Hq=Hq, Hkv=Hkv, seed=53)
     qz, kz, vz = (to_zigzag(x, 4, axis=1) for x in (q, k, v))
@@ -687,7 +688,7 @@ def check_analyze():
     B, S, Hq, Hkv, D, W = 2, 256, 4, 4, 32, 96
     q, k, v = _data(B=B, S=S, Hq=Hq, Hkv=Hkv, seed=71)
     for P_sp in (4, n_dev):
-        mesh = jax.make_mesh((n_dev // P_sp, P_sp), ("data", "model"))
+        mesh = make_mesh((n_dev // P_sp, P_sp), ("data", "model"))
         B_loc = B // (n_dev // P_sp)
         for strategy in ("tokenring", "ring", "ring_bidir", "window"):
             layout = "contig" if strategy == "window" else "zigzag"
@@ -734,7 +735,7 @@ def check_analyze():
         from repro.core.topology import two_pods
 
         n_pods, n_inner = 2, n_dev // 2
-        mesh2d = jax.make_mesh((n_pods, n_inner), ("pod", "model"))
+        mesh2d = make_mesh((n_pods, n_inner), ("pod", "model"))
         topo = two_pods(n_inner)
         pctx = ParallelContext(
             mesh=mesh2d, data_axis=None, sp_axes=("pod", "model"),
@@ -779,7 +780,7 @@ def check_analyze():
         )
 
     # (3) jaxpr overlap pre-check == compiled-HLO verdict
-    mesh4 = jax.make_mesh((n_dev // 4, 4), ("data", "model"))
+    mesh4 = make_mesh((n_dev // 4, 4), ("data", "model"))
     qz, kz, vz = (to_zigzag(x, 4, axis=1) for x in (q, k, v))
     pos = _positions(S, 4, "zigzag")
     for strategy in ("tokenring", "ring", "ring_bidir"):
@@ -849,7 +850,7 @@ def check_registry_plugin():
         description="toy plugin: all-gather KV, attend locally",
     )
     try:
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         pctx = ParallelContext(
             mesh=mesh, sp_axes=("model",), strategy="toy_allgather",
             impl="xla", block_q=64, block_k=64,
@@ -895,7 +896,7 @@ def check_prefix():
     from repro.serving.engine import ServingEngine
 
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev // 4, 4), ("data", "model"))
+    mesh = make_mesh((n_dev // 4, 4), ("data", "model"))
     cfg = ARCHS["qwen3-1.7b"].reduced(
         n_layers=2, d_model=64, n_heads=4, n_kv_heads=4, d_head=16, d_ff=128,
         vocab_size=97, dtype="float32", param_dtype="float32",
@@ -942,7 +943,7 @@ def check_prefix():
     B, S, Hq, Hkv, D = 2, 256, 4, 4, 32
     q, k, v = _data(B=B, S=S, Hq=Hq, Hkv=Hkv, seed=67)
     for P_sp in (4, n_dev):
-        mesh_p = jax.make_mesh((n_dev // P_sp, P_sp), ("data", "model"))
+        mesh_p = make_mesh((n_dev // P_sp, P_sp), ("data", "model"))
         B_loc = B // (n_dev // P_sp)
         for strategy in ("passkv_ring", "passq_ring"):
             pctx_p = ParallelContext(
@@ -1001,7 +1002,7 @@ def check_resilience():
     from repro.serving.resilience import FaultPlan, FaultSpec
 
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev // 4, 4), ("data", "model"))
+    mesh = make_mesh((n_dev // 4, 4), ("data", "model"))
     cfg = ARCHS["qwen3-1.7b"].reduced(
         n_layers=2, d_model=64, n_heads=4, n_kv_heads=4, d_head=16, d_ff=128,
         vocab_size=97, dtype="float32", param_dtype="float32",

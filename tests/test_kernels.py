@@ -410,20 +410,25 @@ def test_paged_decode_dead_row_is_merge_identity(impl):
 
 
 def test_paged_decode_vmem_shapes_lintable():
-    """kernel_buffer_shapes prices the paged kernel's blocks (group x page),
-    and the analyze-gate lint set is clean at serving shape points."""
+    """kernel_buffer_shapes prices the paged kernel's blocks (Hkv x group
+    queries against one whole page), and the analyze-gate lint set is clean
+    at serving shape points."""
     from repro.analysis.kernel_lint import (
         lint_paged_decode_config,
         vmem_estimate,
     )
 
     est = vmem_estimate(
-        "paged_decode", block_q=8, block_k=128, D=128, data_bytes=2
+        "paged_decode", block_q=8, block_k=128, D=128, data_bytes=2,
+        n_kv_heads=8,
     )
     assert 0 < est < 16 * 2**20
+    assert est > vmem_estimate(
+        "paged_decode", block_q=8, block_k=128, D=128, data_bytes=2
+    )
     findings = lint_paged_decode_config(
-        group=8, page_size=128, n_pages=64, table_width=8, D=128,
-        data_bytes=2, subject="t",
+        group=8, page_size=128, n_kv_heads=8, n_pages=64, table_width=8,
+        D=128, data_bytes=2, subject="t",
     )
     assert findings == []
 

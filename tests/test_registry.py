@@ -9,6 +9,7 @@ paper's closed forms.
 
 import pytest
 
+from repro.core.compat import make_mesh
 from repro.core.strategies import (
     KV_RESIDENT_MARGIN,
     CommCost,
@@ -149,11 +150,9 @@ def test_cross_attention_prices_kv_on_its_own_length():
 def test_hybrid_eligibility_uses_inner_degree():
     """Head divisibility for a hybrid plan is judged at the intra-pod ring
     size, not the flattened SP degree."""
-    import jax
-
     from repro.core.api import AttnShapes, ParallelContext
 
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = make_mesh((1, 1, 1), ("pod", "data", "model"))
     shapes = AttnShapes(B=1, Sq=256, Hq=4, Hkv=2, D=32, dtype_bytes=4)
     plan = ParallelContext(
         mesh=mesh, sp_axes=("pod", "model"), strategy="ulysses"
@@ -207,11 +206,9 @@ def test_serving_strategies_registered_and_priced():
 def test_plan_decode_and_prefill_carry_cost():
     """plan_decode / plan_prefill resolve the serving schedule with priced
     plans — the serving analog of the training plan surface."""
-    import jax
-
     from repro.core.api import AttnShapes, ParallelContext
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     pctx = ParallelContext(mesh=mesh, sp_axes=("model",))
     shapes = AttnShapes(B=2, Sq=1, Hq=8, Hkv=2, D=64, Sk=4096, dtype_bytes=4)
     plan = pctx.plan_decode(shapes=shapes)
@@ -237,11 +234,9 @@ def test_plan_decode_and_prefill_carry_cost():
 def test_explicit_serving_strategy_rejected_by_attention_plan():
     """strategy='decode' on the training path is a planning error, not a
     silent mis-schedule."""
-    import jax
-
     from repro.core.api import AttnShapes, ParallelContext
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     pctx = ParallelContext(mesh=mesh, sp_axes=("model",), strategy="decode")
     with pytest.raises(ValueError, match="serving-side"):
         pctx.plan(AttnShapes(B=1, Sq=256, Hq=4, Hkv=4, D=32))
@@ -284,11 +279,9 @@ def test_no_eligible_strategy_raises():
 
 def test_plan_surface_single_process():
     """Planning is pure shape arithmetic: exercisable on one device."""
-    import jax
-
     from repro.core.api import AttnShapes, ParallelContext
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     pctx = ParallelContext(mesh=mesh, sp_axes=("model",), strategy="auto")
     shapes = AttnShapes(B=2, Sq=256, Hq=6, Hkv=6, D=32, dtype_bytes=4)
     plan = pctx.plan(shapes, causal=True)
@@ -308,11 +301,9 @@ def test_plan_surface_single_process():
 
 
 def test_plan_hybrid_inner_validation():
-    import jax
-
     from repro.core.api import AttnShapes, ParallelContext
 
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = make_mesh((1, 1, 1), ("pod", "data", "model"))
     shapes = AttnShapes(B=1, Sq=256, Hq=4, Hkv=4, D=32, dtype_bytes=4)
     plan = ParallelContext(
         mesh=mesh, sp_axes=("pod", "model"), strategy="tokenring"
@@ -358,8 +349,6 @@ def test_paged_block_table_cost_term():
     """``table_pages`` prices the paged cache's per-step block-table
     broadcast on top of the (page-location-independent) psum payload, for
     both serving schedules, and ``plan_decode``/``plan_prefill`` thread it."""
-    import jax
-
     from repro.core.api import AttnShapes, ParallelContext
 
     B, S, Hq, Hkv, D, P, W = 2, 1, 8, 2, 64, 4, 128
@@ -377,7 +366,7 @@ def test_paged_block_table_cost_term():
         )
         assert long.fwd_bytes == paged.fwd_bytes, name
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     pctx = ParallelContext(mesh=mesh, sp_axes=("model",))
     shapes = AttnShapes(B=2, Sq=1, Hq=8, Hkv=2, D=64, Sk=4096, dtype_bytes=4)
     plan = pctx.plan_decode(shapes=shapes, table_pages=W)

@@ -1,0 +1,165 @@
+"""Compile the main-path Pallas kernels for a described TPU v5e.
+
+Nothing here runs on a chip: the TPU compiler that ships with jaxlib compiles
+for a ``v5e:2x2`` topology that is described, not attached, and refuses what
+the chip's compiler would refuse — block shapes that break Mosaic's (8, 128)
+tiling rule, more VMEM than a kernel may use, programs that do not fit HBM.
+Interpret mode (``tests/test_kernels.py``) checks results and cannot see any
+of that.  Widths are Qwen3-1.7B's attention (Hq 16, Hkv 8, D 128, bf16).
+
+The topology is described inside a module fixture, never at import: only one
+process may load the TPU library, and pytest-xdist workers import every test
+file.
+"""
+
+from __future__ import annotations
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+HQ, HKV, D = 16, 8, 128
+S = 2048
+PAGE, PAGES_PER_SLOT, BATCH = 128, 32, 8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without one; keep these compiles out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled_text(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the compiled program"
+    return text
+
+
+def _flash_args(sharding, S=S, B=1):
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    return (
+        _spec((B, S, HQ, D), bf16, sharding),
+        _spec((B, S, HKV, D), bf16, sharding),
+        _spec((B, S, HKV, D), bf16, sharding),
+        _spec((B, S), i32, sharding),
+    )
+
+
+@pytest.mark.parametrize("B", [1, 2])
+def test_flash_forward_compiles_for_v5e(one_chip, B):
+    from repro.kernels.ops import flash_attention
+
+    def fwd(q, k, v, pos):
+        return flash_attention(
+            q, k, v, q_pos=pos, k_pos=pos, causal=True, impl="pallas"
+        )
+
+    _compiled_text(fwd, *_flash_args(one_chip, B=B))
+
+
+def test_flash_backward_compiles_for_v5e(one_chip):
+    """``jax.grad`` through the flash custom_vjp runs both backward kernels
+    (dq; dk/dv), with the ``+ dlse`` cotangent flowing since lse is used."""
+    from repro.kernels.ops import flash_attention
+
+    def loss(q, k, v, pos):
+        out, lse = flash_attention(
+            q, k, v, q_pos=pos, k_pos=pos, causal=True, impl="pallas"
+        )
+        return jnp.sum(out.astype(jnp.float32)) + jnp.sum(lse)
+
+    text = _compiled_text(
+        jax.grad(loss, argnums=(0, 1, 2)), *_flash_args(one_chip, B=2)
+    )
+    assert text.count("tpu_custom_call") >= 3  # fwd + dq + dk/dv
+
+
+@pytest.mark.parametrize("page", [16, PAGE])
+def test_paged_decode_compiles_for_v5e(one_chip, page):
+    from repro.kernels.ops import paged_decode_attention
+
+    n_pages = BATCH * PAGES_PER_SLOT * PAGE // page
+    W = PAGES_PER_SLOT * PAGE // page
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    args = (
+        _spec((BATCH, 1, HQ, D), bf16, one_chip),
+        _spec((n_pages, page, HKV, D), bf16, one_chip),
+        _spec((n_pages, page, HKV, D), bf16, one_chip),
+        _spec((n_pages, page), i32, one_chip),
+        _spec((BATCH, W), i32, one_chip),
+        _spec((BATCH, 1), i32, one_chip),
+    )
+
+    def decode(q, kp, vp, pp, bt, qp):
+        return paged_decode_attention(q, kp, vp, pp, bt, qp, impl="pallas")
+
+    _compiled_text(decode, *args)
+
+
+def test_tokenring_sp_attention_compiles_for_v5e_2x2(topo):
+    """The paper's path on the four described chips: zigzag-causal TokenRing
+    with the pipelined overlap executor, forward and backward, lowered
+    through shard_map onto a ``("data", "model") = (1, 4)`` mesh.  The TPU
+    program's permutes (async start/done pairs inside a loop with no
+    ``known_trip_count``) carry exactly the modeled bytes per direction plus
+    the traveling query halves' int32 positions."""
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core.api import AttnShapes, ParallelContext, sp_attention
+    from repro.core.compat import device_mesh
+    from repro.launch.hlo_analysis import analyze_hlo
+
+    n_dev, S_glob = 4, 4 * S
+    mesh = device_mesh(np.array(topo.devices).reshape(1, n_dev), ("data", "model"))
+    pctx = ParallelContext(
+        mesh=mesh, sp_axes=("model",), data_axis="data", strategy="tokenring",
+        layout="zigzag", impl="pallas", overlap=True,
+    )
+    seq = NamedSharding(mesh, P("data", "model"))
+    args = _flash_args(seq, S=S_glob)
+
+    def attn(q, k, v, pos):
+        return sp_attention(q, k, v, pos, pos, pctx=pctx, causal=True)
+
+    def loss(q, k, v, pos):
+        return jnp.sum(attn(q, k, v, pos).astype(jnp.float32))
+
+    stats = analyze_hlo(_compiled_text(attn, *args), world=n_dev)
+    cost = pctx.plan(
+        AttnShapes(B=1, Sq=S_glob, Hq=HQ, Hkv=HKV, D=D, dtype_bytes=2), causal=True
+    ).cost
+    pos_bytes = (n_dev - 1) * (S_glob // n_dev // 2) * 4
+    assert (stats.link_bytes_fwd, stats.link_bytes_bwd) == (
+        cost.fwd_bytes + pos_bytes, cost.bwd_bytes + pos_bytes,
+    )
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *args)
+    assert "collective-permute" in text
