@@ -451,16 +451,22 @@ def _run_step(
         kp = kps[0] if len(kps) == 1 else jnp.concatenate(kps, axis=1)
         return compute_fn(q, q_pos, k, v, kp)
 
+    # The name scopes are metadata only: they put ``ring_send``,
+    # ``ring_compute`` or ``ring_merge`` in each op's name path, the
+    # backward's ops included (under ``transpose(jvp())``), which is how a
+    # profile attributes device time to the parts of a ring step.
     writes: dict[str, Any] = {}
     if overlap:
         # Pipelined: sends first, payloads straight off the snapshot — no
         # data path from this step's flash into any transfer.
-        for op in step.sends:
-            payload = tuple(snapshot[b] for b in op.buffers)
-            received = shift_fn(payload, mesh_axis(op), op.shift)
-            writes.update(zip(op.targets, received))
-        for op in step.computes:
-            writes[op.out] = run_compute(op)
+        with jax.named_scope("ring_send"):
+            for op in step.sends:
+                payload = tuple(snapshot[b] for b in op.buffers)
+                received = shift_fn(payload, mesh_axis(op), op.shift)
+                writes.update(zip(op.targets, received))
+        with jax.named_scope("ring_compute"):
+            for op in step.computes:
+                writes[op.out] = run_compute(op)
     else:
         # Sequential reference: compute first, then tie every send payload to
         # a compute result — identical values, legacy merge→rotate dependency
@@ -471,32 +477,35 @@ def _run_step(
         # sanitized first — a fully-masked row's lse is ``-inf`` and
         # ``0 * -inf`` would inject NaN.
         marker = None
-        for op in step.computes:
-            writes[op.out] = run_compute(op)
-            lse = writes[op.out][1]
-            # every compute folds into the marker — a step with several
-            # flash calls (split-Q bidir) must serialize sends behind all
-            tie = (
-                jnp.nan_to_num(lse.ravel()[0], nan=0.0, posinf=0.0, neginf=0.0)
-                * 0.0
-            )
-            marker = tie if marker is None else marker + tie
-        for op in step.sends:
-            payload = tuple(snapshot[b] for b in op.buffers)
-            if marker is not None:
-                payload, _ = lax.optimization_barrier((payload, marker))
-                payload = jax.tree.map(
-                    lambda x: x + marker.astype(x.dtype), payload
+        with jax.named_scope("ring_compute"):
+            for op in step.computes:
+                writes[op.out] = run_compute(op)
+                lse = writes[op.out][1]
+                # every compute folds into the marker — a step with several
+                # flash calls (split-Q bidir) must serialize sends behind all
+                tie = (
+                    jnp.nan_to_num(lse.ravel()[0], nan=0.0, posinf=0.0, neginf=0.0)
+                    * 0.0
                 )
-            received = shift_fn(payload, mesh_axis(op), op.shift)
-            writes.update(zip(op.targets, received))
+                marker = tie if marker is None else marker + tie
+        with jax.named_scope("ring_send"):
+            for op in step.sends:
+                payload = tuple(snapshot[b] for b in op.buffers)
+                if marker is not None:
+                    payload, _ = lax.optimization_barrier((payload, marker))
+                    payload = jax.tree.map(
+                        lambda x: x + marker.astype(x.dtype), payload
+                    )
+                received = shift_fn(payload, mesh_axis(op), op.shift)
+                writes.update(zip(op.targets, received))
 
     out = dict(bufs)
     out.update(writes)  # commit — generation g+1
-    for op in step.merges:
-        o, l = out[op.dest]
-        po, pl = out[op.src]
-        out[op.dest] = merge_partials(o, l, po, pl)
+    with jax.named_scope("ring_merge"):
+        for op in step.merges:
+            o, l = out[op.dest]
+            po, pl = out[op.src]
+            out[op.dest] = merge_partials(o, l, po, pl)
     return out
 
 

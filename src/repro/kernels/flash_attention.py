@@ -328,6 +328,7 @@ def flash_attention_fwd_pallas(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )
     out, lse = call(q_pos.reshape(B, 1, Sq), k_pos.reshape(B, 1, Sk), q, k, v)
     return out, lse.reshape(B, Hq, Sq)
@@ -552,6 +553,7 @@ def flash_attention_bwd_pallas(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dq",
     )
     dq = dq_call(q_pos, k_pos, q, k, v, dout, *rows)
 
@@ -597,6 +599,7 @@ def flash_attention_bwd_pallas(
             ),
         ),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )
     dk, dv = dkv_call(q_pos, k_pos, q, k, v, dout, *rows)
     return dq, dk, dv

@@ -274,6 +274,7 @@ def paged_decode_fwd_pallas(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="paged_decode",
     )(
         bt, qp, q.reshape(B, Hkv, group, D), k_pool, v_pool,
         pos_pool.reshape(n_pages, 1, page_size),

@@ -77,29 +77,36 @@ def build_model(cfg: ArchConfig, pctx: ParallelContext) -> ModelBundle:
     if fam in ("dense", "moe", "vlm"):
         from repro.models import transformer as T
 
+        # The serving steps are named functions, not lambdas: a jitted step
+        # compiles to the module ``jit_<name>`` (``jit_decode_step_paged``,
+        # ``jit_prefill_chunk_paged``), which is how a profile tells them apart.
+        def decode_step(params, tok, state, active=None):
+            return T.lm_decode_step(params, tok, state, active, cfg=cfg, pctx=pctx)
+
+        def prefill_chunk(params, tok, state, n_valid):
+            return T.lm_prefill_chunk(params, tok, state, n_valid, cfg=cfg, pctx=pctx)
+
+        def decode_step_paged(params, tok, state, active=None):
+            return T.lm_decode_step_paged(params, tok, state, active, cfg=cfg, pctx=pctx)
+
+        def prefill_chunk_paged(params, tok, state, n_valid):
+            return T.lm_prefill_chunk_paged(params, tok, state, n_valid, cfg=cfg, pctx=pctx)
+
         return ModelBundle(
             cfg=cfg,
             pctx=pctx,
             init=partial(_init_wrap, T.init_lm, cfg),
             loss=lambda params, batch: T.lm_loss(params, batch, cfg=cfg, pctx=pctx),
-            decode_step=lambda params, tok, state, active=None: T.lm_decode_step(
-                params, tok, state, active, cfg=cfg, pctx=pctx
-            ),
+            decode_step=decode_step,
             init_serve_state=lambda B, max_len: T.init_decode_cache(
                 cfg, B, max_len, pctx
             ),
             prefill=lambda params, tokens, positions, cache, prefix_embeds=None: T.lm_prefill(
                 params, tokens, positions, cache, prefix_embeds, cfg=cfg, pctx=pctx
             ),
-            prefill_chunk=lambda params, tok, state, n_valid: T.lm_prefill_chunk(
-                params, tok, state, n_valid, cfg=cfg, pctx=pctx
-            ),
-            decode_step_paged=lambda params, tok, state, active=None: T.lm_decode_step_paged(
-                params, tok, state, active, cfg=cfg, pctx=pctx
-            ),
-            prefill_chunk_paged=lambda params, tok, state, n_valid: T.lm_prefill_chunk_paged(
-                params, tok, state, n_valid, cfg=cfg, pctx=pctx
-            ),
+            prefill_chunk=prefill_chunk,
+            decode_step_paged=decode_step_paged,
+            prefill_chunk_paged=prefill_chunk_paged,
             init_paged_state=lambda n_pages, page_size, max_batch, slot_pages: T.init_paged_decode_cache(
                 cfg, n_pages=n_pages, page_size=page_size,
                 max_batch=max_batch, slot_pages=slot_pages, pctx=pctx

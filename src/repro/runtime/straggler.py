@@ -21,15 +21,17 @@ class StragglerDetector:
         self.window = deque(maxlen=window)
         self.threshold = threshold
         self.warmup = warmup
-        self.events: list[tuple[int, float, float]] = []
+        # (step, seconds, median seconds, the step's phase seconds or None)
+        self.events: list[tuple[int, float, float, dict | None]] = []
 
-    def record(self, step: int, seconds: float) -> str | None:
-        """Returns a description if this step is anomalous, else None."""
+    def record(self, step: int, seconds: float, phases: dict | None = None) -> str | None:
+        """Returns a description if this step is anomalous, else None.
+        ``phases`` (name -> seconds of this step) is kept with an event."""
         if len(self.window) >= self.warmup:
             med = float(np.median(self.window))
             mad = float(np.median(np.abs(np.asarray(self.window) - med))) or med * 0.05
             if seconds > med + self.threshold * mad and seconds > 1.5 * med:
-                self.events.append((step, seconds, med))
+                self.events.append((step, seconds, med, phases))
                 self.window.append(seconds)
                 return f"{seconds*1e3:.1f} ms vs median {med*1e3:.1f} ms"
         self.window.append(seconds)

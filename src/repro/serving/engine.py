@@ -66,6 +66,7 @@ import numpy as np
 
 from repro.kernels.flash_attention import PAD_POS
 from repro.runtime.straggler import StragglerDetector
+from repro.runtime.tracing import span
 from repro.serving.kv_cache import PageAllocator, PrefixIndex, pages_for
 from repro.serving.resilience import (
     CacheAuditor,
@@ -93,8 +94,14 @@ class Request:
     retries: int = 0  # quarantine rounds survived so far
     error: str | None = None  # last fault message (retrying/failed)
     t_submit: float = 0.0
+    t_admit: float | None = None  # first admission into a slot
     t_first: float | None = None
     t_done: float | None = None
+
+    @property
+    def prefilled(self) -> int:
+        """Tokens of the request written to the cache by prefill so far."""
+        return getattr(self, "_filled", 0)
 
 
 class ServingEngine:
@@ -280,7 +287,6 @@ class ServingEngine:
             "integrity_errors": 0,
             "load_shed": 0,
             "snapshots": 0,
-            "straggler_events": 0,
         }
 
         # ---- resilience layer (serving/resilience.py) -------------------
@@ -293,6 +299,9 @@ class ServingEngine:
         self.auditor = CacheAuditor(self)
         self.straggler = straggler if straggler is not None else StragglerDetector()
         self._tick = 0
+        # Host seconds per phase span (``engine.tick``, ``engine.admit``, ...)
+        # summed since construction; see ``run`` for what each one covers.
+        self.phase_s: dict[str, float] = {}
         if snapshot_dir is not None:
             from repro.checkpoint.manager import CheckpointManager
 
@@ -358,39 +367,62 @@ class ServingEngine:
         saw a fault ends with a cache audit; periodic audits run every
         ``audit_every`` ticks and periodic snapshots every
         ``snapshot_every``.  Audit violations restore the latest snapshot
-        (or raise when none exists)."""
+        (or raise when none exists).
+
+        Each tick is a host span ``engine.tick`` (carrying the tick number)
+        with the phases inside it: ``engine.admit`` per admitted request
+        (its uid), ``engine.prefill`` (build the chunk batch, dispatch it),
+        ``engine.decode`` (grow pages, build, dispatch), ``engine.sync_bt``
+        (block-table upload, inside either), ``engine.sample`` (the host sync
+        on the sampled tokens and the retire loop) and ``engine.resilience``
+        (audit, ladder, snapshot).  Their seconds add up in ``phase_s``; a
+        straggler event keeps its own tick's."""
         for _ in range(max_steps):
-            self._tick += 1
+            if not self._run_tick():
+                break
+        return self.done
+
+    def _run_tick(self) -> bool:
+        """One tick; False when there was nothing to serve."""
+        self._tick += 1
+        before = dict(self.phase_s)
+        with span("engine.tick", self.phase_s, tick=self._tick):
             t0 = time.perf_counter()
             faults_before = self.counters["faults"]
             try:
                 self._admit()
                 if all(s is None for s in self.slots) and not self.queue:
-                    break
+                    return False
                 if self._chunked:
                     self._prefill_tick()
                 self._decode_once()
             except ServingFault as e:
                 self._recover(e)
-            if self.counters["faults"] > faults_before:
-                self._post_recovery_audit()
-            else:
-                self.ladder.record_clean(self._tick)
-                if self.audit_every and self._tick % self.audit_every == 0:
-                    try:
-                        self.auditor.check()
-                    except IntegrityError as e:
-                        self._recover(e)
-            if self.straggler.record(self._tick, time.perf_counter() - t0):
-                self.counters["straggler_events"] += 1
-            if (
-                self._ckpt is not None
-                and self.snapshot_every
-                and self._tick % self.snapshot_every == 0
-                and (self.queue or any(s is not None for s in self.slots))
-            ):
-                self.snapshot()
-        return self.done
+            with span("engine.resilience", self.phase_s):
+                self._tick_resilience(faults_before)
+            dt = time.perf_counter() - t0
+            phases = {k: v - before.get(k, 0.0) for k, v in self.phase_s.items()
+                      if v != before.get(k)}
+            self.straggler.record(self._tick, dt, phases=phases)
+        return True
+
+    def _tick_resilience(self, faults_before: int):
+        if self.counters["faults"] > faults_before:
+            self._post_recovery_audit()
+        else:
+            self.ladder.record_clean(self._tick)
+            if self.audit_every and self._tick % self.audit_every == 0:
+                try:
+                    self.auditor.check()
+                except IntegrityError as e:
+                    self._recover(e)
+        if (
+            self._ckpt is not None
+            and self.snapshot_every
+            and self._tick % self.snapshot_every == 0
+            and (self.queue or any(s is not None for s in self.slots))
+        ):
+            self.snapshot()
 
     # ------------------------------------------------- fault handling
 
@@ -508,8 +540,10 @@ class ServingEngine:
                 break
             req = self.queue[qi]
             try:
-                self._fire("admit", uid=req.uid)
-                if not self._admit_into(i, qi, req):
+                with span("engine.admit", self.phase_s, uid=req.uid):
+                    self._fire("admit", uid=req.uid)
+                    admitted = self._admit_into(i, qi, req)
+                if not admitted:
                     # Page exhaustion: strict FCFS — later requests wait
                     # behind the head rather than starving it.
                     break
@@ -576,6 +610,8 @@ class ServingEngine:
         self.queue.pop(qi)
         self.slots[i] = req
         req.status = "running"
+        if req.t_admit is None:
+            req.t_admit = time.perf_counter()
         self.state = (
             self._reset_slot_to(self.state, i, hit_tokens)
             if self._paged else self._reset_slot(self.state, i)
@@ -615,11 +651,12 @@ class ServingEngine:
         self.state = self._release_pages(self.state, jnp.asarray(padded))
 
     def _prefilling(self, req) -> bool:
-        return getattr(req, "_filled", 0) < len(req._tokens) - 1
+        return req.prefilled < len(req._tokens) - 1
 
     def _sync_bt(self):
         if self._paged and self._bt_dirty:
-            self.state = dict(self.state, block_tables=jnp.asarray(self._bt))
+            with span("engine.sync_bt", self.phase_s):
+                self.state = dict(self.state, block_tables=jnp.asarray(self._bt))
             self._bt_dirty = False
 
     # ---- paged bookkeeping ----------------------------------------------
@@ -736,7 +773,17 @@ class ServingEngine:
 
     def _prefill_tick(self):
         """One scheduler iteration's prefill work: split the token budget
-        FCFS across prefilling slots and run a single batched chunk step."""
+        FCFS across prefilling slots and run a single batched chunk step.
+        Its span notes the step's valid and padded (``max_batch x chunk``)
+        tokens."""
+        with span("engine.prefill", self.phase_s) as sp:
+            valid = self._prefill_dispatch()
+            if valid:
+                sp.note(valid_tokens=valid,
+                        padded_tokens=self.max_batch * self.prefill_chunk)
+
+    def _prefill_dispatch(self) -> int:
+        """Build the chunk batch and dispatch it; returns its valid tokens."""
         self._fire("prefill_tick")
         prefilling = [
             (i, r) for i, r in enumerate(self.slots)
@@ -746,7 +793,7 @@ class ServingEngine:
         # into a lower slot must not preempt an older request's budget.
         prefilling.sort(key=lambda t: t[1].uid)
         if not prefilling:
-            return
+            return 0
         n_decode = sum(
             1 for r in self.slots if r is not None and not self._prefilling(r)
         )
@@ -770,13 +817,14 @@ class ServingEngine:
             n_valid[i] = a
             budget -= a
         if not n_valid.any():
-            return
+            return 0
         self._sync_bt()
         _, self.state = self._chunk_step(
             self.params, jnp.asarray(tokens), self.state, jnp.asarray(n_valid)
         )
+        valid = int(n_valid.sum())
         self.counters["prefill_steps"] += 1
-        self.counters["prefill_tokens"] += int(n_valid.sum())
+        self.counters["prefill_tokens"] += valid
         for i, req in prefilling:
             req._filled += int(n_valid[i])
             req._cached += int(n_valid[i])
@@ -795,6 +843,7 @@ class ServingEngine:
                     # the slot's prefill allocation; its first decode waits
                     # for the next iteration so the budget cap holds.
                     self._hold_decode.add(i)
+        return valid
 
     # ---- token-by-token fallback (families without prefill_chunk) -------
 
@@ -832,6 +881,15 @@ class ServingEngine:
         return jax.random.categorical(sub, logits / self.temperature).astype(jnp.int32)
 
     def _decode_once(self):
+        with span("engine.decode", self.phase_s):
+            logits, active = self._decode_dispatch()
+        if active:
+            with span("engine.sample", self.phase_s):
+                self._sample_and_retire(logits, active)
+
+    def _decode_dispatch(self):
+        """Grow pages, build the decode batch and dispatch the step; returns
+        its logits and the slots it decoded (none: nothing to decode)."""
         self._fire("decode_once")
         hold, self._hold_decode = self._hold_decode, set()
         if self._paged:
@@ -844,7 +902,7 @@ class ServingEngine:
             toks[i] = req._next_token
             active.append(i)
         if not active:
-            return
+            return None, active
         self._sync_bt()
         if self._chunked:
             mask = np.zeros((self.max_batch,), bool)
@@ -857,6 +915,11 @@ class ServingEngine:
                 self.params, jnp.asarray(toks), self.state
             )
         self.counters["decode_steps"] += 1
+        return logits, active
+
+    def _sample_and_retire(self, logits, active):
+        """Sample the next tokens (the host waits for the step here), then
+        stamp, append and retire each decoded slot's request."""
         nxt = np.asarray(self._sample(logits))
         now = time.perf_counter()
         for i in active:
@@ -1002,9 +1065,14 @@ class ServingEngine:
             "mode": self.ladder.name,
             "escalations": self.ladder.escalations,
         }
+        out["phase_s"] = dict(self.phase_s)
         out["step_time"] = {
             "median_s": self.straggler.median,
             "straggler_events": len(self.straggler.events),
+            "slow_ticks": [
+                {"tick": t, "seconds": dt, "median_s": med, "phase_s": ph}
+                for t, dt, med, ph in self.straggler.events
+            ],
         }
         if self._paged:
             out["pages"] = self.alloc.utilization()

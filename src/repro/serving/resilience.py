@@ -438,6 +438,7 @@ def _request_record(req) -> dict:
         "next_token": getattr(req, "_next_token", None),
         "ready_tick": int(getattr(req, "_ready_tick", 0)),
         "t_submit": req.t_submit,
+        "t_admit": req.t_admit,
         "t_first": req.t_first,
         "t_done": req.t_done,
     }
@@ -465,6 +466,7 @@ def _request_from(rec: dict):
         req._next_token = int(rec["next_token"])
     req._ready_tick = int(rec["ready_tick"])
     req.t_submit = rec["t_submit"]
+    req.t_admit = rec.get("t_admit")  # absent from older snapshots
     req.t_first = rec["t_first"]
     req.t_done = rec["t_done"]
     return req

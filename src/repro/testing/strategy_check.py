@@ -1067,6 +1067,52 @@ def check_resilience():
     )
 
 
+def check_ring_scopes():
+    """A TokenRing step (zigzag, pipelined, forward + vjp) names its parts:
+    the compiled HLO's op paths carry ``ring_send``, ``ring_compute`` and
+    ``ring_merge``, in the forward's ops and in the backward's (under
+    ``transpose(jvp())``), and the scopes are metadata only: with them
+    turned off the outputs are bitwise the same."""
+    import re
+    import contextlib
+    from unittest import mock
+
+    n_dev = len(jax.devices())
+    mesh = make_mesh((n_dev // 4, 4), ("data", "model"))
+    q, k, v = _data(B=1, S=256, Hq=4, Hkv=2, D=32, seed=7)
+    qz, kz, vz = (to_zigzag(x, 4, axis=1) for x in (q, k, v))
+    pos = _positions(256, 4, "zigzag")[None]
+    pctx = ParallelContext(
+        mesh=mesh, sp_axes=("model",), data_axis="data", strategy="tokenring",
+        layout="zigzag", impl="xla", block_q=64, block_k=64, overlap=True,
+    )
+
+    def build():
+        def sp_step(q, k, v, g):
+            out, vjp = jax.vjp(
+                lambda q, k, v: sp_attention(q, k, v, pos, pos, pctx=pctx, causal=True),
+                q, k, v,
+            )
+            return (out, *vjp(g))
+
+        return jax.jit(sp_step).lower(qz, kz, vz, qz).compile()
+
+    scoped = build()
+    with mock.patch.object(jax, "named_scope", lambda name: contextlib.nullcontext()):
+        plain = build()
+    paths = re.findall(r'op_name="([^"]+)"', scoped.as_text())
+    for scope in ("ring_send", "ring_compute", "ring_merge"):
+        for part in ("jit(sp_step)/jvp()", "jit(sp_step)/transpose(jvp())"):
+            assert any(p.startswith(part) and f"/{scope}" in p for p in paths), (
+                f"no {scope} among the op paths under {part}"
+            )
+    assert "ring_merge" not in plain.as_text()
+    for a, b in zip(scoped(qz, kz, vz, qz), plain(qz, kz, vz, qz)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    print(f"PASS ring scopes: send/compute/merge named, outputs bitwise unchanged "
+          f"({n_dev} devices)")
+
+
 CHECKS = {
     "strategies": check_strategies,
     "overlap": check_overlap,
@@ -1085,6 +1131,7 @@ CHECKS = {
     "moe": check_moe,
     "sharded_ce": check_sharded_ce,
     "travel": check_travel_dtype,
+    "scopes": check_ring_scopes,
 }
 
 

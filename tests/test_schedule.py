@@ -328,3 +328,26 @@ def test_executor_merges_match_oracle():
     res2 = execute_schedule(sched2, bufs2, axis_name=None, compute_fn=compute)
     out2, _ = finalize(*res2["acc"])
     np.testing.assert_allclose(np.asarray(out2), np.asarray(ref), atol=1e-5, rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# ring name scopes (four fake devices, in a subprocess)
+
+
+def test_ring_step_scopes_named_and_metadata_only():
+    """A four-device TokenRing step's compiled op paths carry ``ring_send``,
+    ``ring_compute`` and ``ring_merge`` in its forward and backward, and its
+    outputs are bitwise those of the same step without the scopes."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"), REPRO_CHECK_DEVICES="4")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, "-m", "repro.testing.strategy_check", "scopes"],
+        capture_output=True, text=True, timeout=600, env=env, cwd=repo,
+    )
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert "PASS ring scopes" in proc.stdout

@@ -384,3 +384,87 @@ def test_engine_rejects_bad_knobs():
         eng.submit(list(range(8)), max_new_tokens=1)
     with pytest.raises(ValueError, match="empty"):
         eng.submit([], max_new_tokens=1)
+
+
+# ---------------------------------------------------------------------------
+# spans, phase times and the request counters they rest on
+
+PHASES = ("engine.tick", "engine.admit", "engine.prefill", "engine.sync_bt",
+          "engine.decode", "engine.sample", "engine.resilience")
+
+
+def test_paged_engine_fills_phase_seconds():
+    cfg, bundle, params = _setup()
+    eng = ServingEngine(bundle, params, max_batch=2, max_len=64, prefill_chunk=8,
+                        page_size=8)
+    for i in range(3):
+        eng.submit(list(range(1 + i, 20 + i)), max_new_tokens=3)
+    eng.run()
+    ph = eng.stats()["phase_s"]
+    assert set(ph) == set(PHASES)
+    assert all(ph[k] > 0.0 for k in PHASES)
+    inner = ("engine.admit", "engine.prefill", "engine.decode", "engine.sample",
+             "engine.resilience")  # engine.sync_bt lies inside prefill or decode
+    assert sum(ph[k] for k in inner) <= ph["engine.tick"]
+
+
+def test_request_admit_time_and_prefilled_count():
+    """``t_admit`` is stamped once, at first admission; ``prefilled`` reads
+    the engine's own count of prompt tokens in the cache at every step."""
+    cfg, bundle, params = _setup()
+    eng = ServingEngine(bundle, params, max_batch=1, max_len=64, prefill_chunk=8,
+                        page_size=8)
+    a = eng.submit(list(range(1, 21)), max_new_tokens=2)
+    b = eng.submit(list(range(2, 12)), max_new_tokens=2)
+    assert a.t_admit is None and a.prefilled == 0
+    seen = []
+    while a.status != "done" or b.status != "done":
+        eng.run(max_steps=1)
+        for r in (a, b):
+            assert r.prefilled == getattr(r, "_filled", 0)
+        seen.append(a.prefilled)
+    assert seen[:3] == [8, 16, 19]  # 19 prompt tokens prefill; the 20th is decoded
+    assert a.t_submit <= a.t_admit <= a.t_first
+    assert b.t_admit >= a.t_done  # one slot: b waited for a
+    assert b.prefilled == len(b.prompt) - 1
+
+
+def test_slow_tick_keeps_its_phase_times():
+    from repro.runtime.straggler import StragglerDetector
+
+    cfg, bundle, params = _setup()
+    eng = ServingEngine(bundle, params, max_batch=1, max_len=64, prefill_chunk=8,
+                        page_size=8, straggler=StragglerDetector(warmup=3))
+    eng.submit(list(range(1, 5)), max_new_tokens=12)
+    eng.run(max_steps=6)  # compiled and warm
+    dispatch = eng._decode_dispatch
+
+    def slow_dispatch():
+        import time
+
+        time.sleep(0.5)
+        return dispatch()
+
+    eng._decode_dispatch = slow_dispatch
+    eng.run(max_steps=1)
+    eng._decode_dispatch = dispatch
+    # A loaded machine may flag other ticks too; the slowed one is the last.
+    slow = eng.stats()["step_time"]["slow_ticks"][-1]
+    assert slow["tick"] == eng._tick and slow["seconds"] >= 0.5
+    assert slow["phase_s"]["engine.decode"] >= 0.5
+    assert slow["phase_s"]["engine.decode"] < slow["seconds"]
+
+
+def test_paged_steps_lower_under_stable_names():
+    """The two serving programs compile to modules named after their steps,
+    which is how a device profile tells prefill from decode."""
+    cfg, bundle, params = _setup()
+    eng = ServingEngine(bundle, params, max_batch=2, max_len=64, prefill_chunk=8,
+                        page_size=8)
+    B, C = 2, 8
+    pre = eng._chunk_step.lower(params, jnp.zeros((B, C), jnp.int32), eng.state,
+                                jnp.zeros((B,), jnp.int32)).as_text()
+    dec = eng._step.lower(params, jnp.zeros((B,), jnp.int32), eng.state,
+                          jnp.zeros((B,), bool)).as_text()
+    assert "module @jit_prefill_chunk_paged" in pre
+    assert "module @jit_decode_step_paged" in dec

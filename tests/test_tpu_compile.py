@@ -163,3 +163,42 @@ def test_tokenring_sp_attention_compiles_for_v5e_2x2(topo):
     )
     text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *args)
     assert "collective-permute" in text
+
+
+def _named_kernels_text(kernel, sharding):
+    """Compiled v5e HLO of the program that runs ``kernel``."""
+    from repro.kernels.ops import flash_attention, paged_decode_attention
+
+    if kernel == "paged_decode":
+        n_pages, W = BATCH * 4, 4
+        bf16, i32 = jnp.bfloat16, jnp.int32
+        args = (
+            _spec((BATCH, 1, HQ, D), bf16, sharding),
+            _spec((n_pages, PAGE, HKV, D), bf16, sharding),
+            _spec((n_pages, PAGE, HKV, D), bf16, sharding),
+            _spec((n_pages, PAGE), i32, sharding),
+            _spec((BATCH, W), i32, sharding),
+            _spec((BATCH, 1), i32, sharding),
+        )
+        return _compiled_text(
+            lambda *a: paged_decode_attention(*a, impl="pallas"), *args)
+
+    def loss(q, k, v, pos):
+        out, lse = flash_attention(q, k, v, q_pos=pos, k_pos=pos, causal=True, impl="pallas")
+        return jnp.sum(out.astype(jnp.float32)) + jnp.sum(lse)
+
+    return _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *_flash_args(sharding, S=512))
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_decode"])
+def test_kernels_named_in_compiled_v5e_hlo(one_chip, kernel):
+    """Each Pallas kernel is a named instruction of the compiled program, the
+    name a device profile shows for its op.  Transformations prefix it
+    (``jvp_flash_fwd_``, ``transpose_jvp_flash_bwd_dq__``)."""
+    import re
+
+    text = _named_kernels_text(kernel, one_chip)
+    names = [m.group(1) for m in re.finditer(r"^\s*(?:ROOT )?%([\w.\-]+) = .*tpu_custom_call",
+                                             text, re.M)]
+    assert any(re.search(rf"(^|_){kernel}(_|\.|$)", n) for n in names), (
+        f"no custom call named {kernel} among {names}")
