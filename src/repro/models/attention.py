@@ -269,7 +269,7 @@ def attention_prefill_chunk_paged(
     positions,
     k_pool,
     v_pool,
-    old_pos_view,
+    pos_view,
     flat_view,
     write_page,
     write_off,
@@ -282,24 +282,26 @@ def attention_prefill_chunk_paged(
 ):
     """Paged chunked-prefill step: ``x (B,C,d)`` against the gathered view.
 
-    ``old_pos_view`` is gathered from the *pre-chunk* position pool so the
-    resident partial can never see the chunk's own slots (they are attended
-    locally inside ``sp_prefill``); the chunk's K/V scatter into the owned
-    pages afterwards.  ``write_page``/``write_off (B,C)`` carry the drop
-    sentinel for invalid tokens.  Returns ``(y, k_pool', v_pool')``.
+    Every row's chunk K/V scatter into the pool first (``write_page``/
+    ``write_off (B,C)`` carry the drop sentinel for invalid tokens); then
+    each row gathers its resident view from the *written* pool, so a row
+    sees what earlier rows of the same step wrote.  ``pos_view`` holds only
+    the positions before each row's own start (``PAD_POS`` elsewhere): the
+    resident partial never sees the row's own tokens, which are attended
+    locally inside ``sp_prefill``.  Returns ``(y, k_pool', v_pool')``.
     """
     from repro.serving.kv_cache import gather_pages
 
     B, C, _ = x.shape
     q, k, v = _project_qkv(p, x, positions, cfg, rope=rope, pctx=pctx)
-    k_view = constrain(gather_pages(k_pool, flat_view), pctx, _view_spec(pctx))
-    v_view = constrain(gather_pages(v_pool, flat_view), pctx, _view_spec(pctx))
-    out = sp_prefill(
-        q, k, v, positions, k_view, v_view, old_pos_view, positions,
-        pctx=pctx, window=window, table_pages=table_pages,
-    )
     kp = k_pool.at[write_page, write_off].set(k.astype(k_pool.dtype), mode="drop")
     vp = v_pool.at[write_page, write_off].set(v.astype(v_pool.dtype), mode="drop")
+    k_view = constrain(gather_pages(kp, flat_view), pctx, _view_spec(pctx))
+    v_view = constrain(gather_pages(vp, flat_view), pctx, _view_spec(pctx))
+    out = sp_prefill(
+        q, k, v, positions, k_view, v_view, pos_view, positions,
+        pctx=pctx, window=window, table_pages=table_pages,
+    )
     y = dense(p["wo"], out.reshape(B, C, -1), jnp.dtype(cfg.dtype))
     return y, kp, vp
 

@@ -89,8 +89,10 @@ def build_model(cfg: ArchConfig, pctx: ParallelContext) -> ModelBundle:
         def decode_step_paged(params, tok, state, active=None):
             return T.lm_decode_step_paged(params, tok, state, active, cfg=cfg, pctx=pctx)
 
-        def prefill_chunk_paged(params, tok, state, n_valid):
-            return T.lm_prefill_chunk_paged(params, tok, state, n_valid, cfg=cfg, pctx=pctx)
+        def prefill_chunk_paged(params, tok, state, n_valid, row_slot=None, row_start=None):
+            return T.lm_prefill_chunk_paged(
+                params, tok, state, n_valid, row_slot, row_start, cfg=cfg, pctx=pctx
+            )
 
         return ModelBundle(
             cfg=cfg,

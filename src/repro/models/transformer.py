@@ -350,43 +350,57 @@ def init_paged_decode_cache(
     )
 
 
-def lm_prefill_chunk_paged(params, token_ids, cache, n_valid, *, cfg, pctx):
+def lm_prefill_chunk_paged(
+    params, token_ids, cache, n_valid, row_slot=None, row_start=None, *, cfg, pctx
+):
     """Paged chunked prefill: the page-pool analog of :func:`lm_prefill_chunk`.
 
     Same contract (``token_ids (B, C)``, ``n_valid (B,)``, skipped rows
-    untouched, logits at each row's last valid position) — only the cache
-    layout differs: row ``b``'s valid tokens land in the pages its block
-    table maps for logical slots ``[len_b, len_b + n_valid_b)``.  The engine
-    guarantees those table entries are mapped before calling (admission
-    allocates prompt pages); unmapped entries drop the write and mask the
-    read, so a bookkeeping bug degrades to masked garbage, never to a write
-    on someone else's page.
+    untouched, logits at each row's last valid position) — except that a
+    row is not tied to a slot.  ``row_slot (B,)`` names the slot whose block
+    table row ``b`` writes through and ``row_start (B,)`` its first position
+    (defaults: the identity and ``cache["len"]``, one row per slot).  Several
+    rows may carry consecutive chunks of one slot's prompt: all rows write
+    their K/V first, then each attends to every position before its own
+    start, those written by earlier rows of this step included.  A slot's
+    ``len`` becomes the largest ``row_start + n_valid`` over its rows with
+    tokens.  The engine guarantees every written table entry is mapped
+    before calling (admission allocates prompt pages); unmapped entries drop
+    the write and mask the read, so a bookkeeping bug degrades to masked
+    garbage, never to a write on someone else's page.
     """
+    from repro.kernels.flash_attention import PAD_POS
     from repro.serving.kv_cache import gather_positions, view_indices, write_coords
 
     B, C = token_ids.shape
     n_pages, page_size = cache["pos"].shape
-    bt = cache["block_tables"]
-    length = cache["len"]  # (B,)
+    length = cache["len"]  # (n_slots,)
+    if row_slot is None:
+        row_slot = jnp.arange(B, dtype=jnp.int32)
+    if row_start is None:
+        row_start = length[row_slot]
+    bt = cache["block_tables"][row_slot]  # (B, W): each row's slot's table
     offs = jnp.arange(C, dtype=jnp.int32)[None, :]
-    positions = length[:, None].astype(jnp.int32) + offs  # (B, C)
+    positions = row_start[:, None] + offs  # (B, C)
     valid = offs < n_valid[:, None]
     write_page, write_off = write_coords(
         bt, positions, valid, n_pages, page_size
     )
-    # Resident view clamped to the pages the *pre-chunk* length actually
-    # uses: stale mappings beyond it gather as fill, never as data.
-    flat_view = view_indices(bt, page_size, lengths=length)
-    # Pre-chunk position view: the resident partial must not see the chunk's
-    # own slots (they are attended locally, pre-write).
-    old_pos_view = gather_positions(cache["pos"], flat_view)
+    pos_pool = cache["pos"].at[write_page, write_off].set(positions, mode="drop")
+    # Resident view clamped to the pages the row's start actually uses:
+    # stale mappings beyond it gather as fill, never as data.  Positions at
+    # or past the start (the row's own tokens and later rows') are masked:
+    # the row attends its own chunk locally, and later chunks not at all.
+    flat_view = view_indices(bt, page_size, lengths=row_start)
+    pos_view = gather_positions(pos_pool, flat_view)
+    pos_view = jnp.where(pos_view < row_start[:, None], pos_view, PAD_POS)
     x = params["embed"]["table"][token_ids].astype(jnp.dtype(cfg.dtype))
 
     def body(x, xs):
         p_l, kc_l, vc_l = xs
         h = apply_norm(p_l["ln1"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
         y, kc_l, vc_l = attention_prefill_chunk_paged(
-            p_l["attn"], h, positions, kc_l, vc_l, old_pos_view, flat_view,
+            p_l["attn"], h, positions, kc_l, vc_l, pos_view, flat_view,
             write_page, write_off, cfg=cfg, pctx=pctx, window=cfg.window,
             table_pages=bt.shape[1],
         )
@@ -406,12 +420,16 @@ def lm_prefill_chunk_paged(params, token_ids, cache, n_valid, *, cfg, pctx):
         "bd,dv->bv", last.astype(jnp.dtype(cfg.dtype)),
         _lm_head_w(params, cfg).astype(jnp.dtype(cfg.dtype)),
     )
+    # Rows without tokens scatter to an out-of-range slot and drop.
+    owner = jnp.where(n_valid > 0, row_slot, length.shape[0])
     new_cache = {
         "k": ks,
         "v": vs,
-        "pos": cache["pos"].at[write_page, write_off].set(positions, mode="drop"),
-        "block_tables": bt,
-        "len": length + n_valid.astype(length.dtype),
+        "pos": pos_pool,
+        "block_tables": cache["block_tables"],
+        "len": length.at[owner].max(
+            (row_start + n_valid).astype(length.dtype), mode="drop"
+        ),
     }
     return logits, new_cache
 

@@ -113,9 +113,11 @@ class ServingEngine:
         paged mode rounds ``max_len`` up to ``slot_pages = ceil(max_len /
         page_size)`` pages of *logical* capacity per slot, while physical
         memory is the shared pool below.
-      * ``prefill_chunk`` — prompt tokens fed per chunked-prefill step (the
-        static chunk width; prompt tails ride along as partial chunks, so
-        there is exactly one compilation).
+      * ``prefill_chunk`` — prompt tokens per row of a chunked-prefill
+        step (the static chunk width; prompt tails ride along as partial
+        chunks, so there is exactly one compilation).  Paged mode fills the
+        rows that prefilling requests leave empty with their later chunks,
+        so a request alone prefills ``max_batch`` chunks a step.
       * ``token_budget`` — meters *prefill*: an iteration grants prefilling
         slots at most ``token_budget - n_decoding`` tokens (FCFS).  Decode is
         indivisible — every decoding slot emits one token per iteration
@@ -194,7 +196,13 @@ class ServingEngine:
                 self.max_pages, page_size, max_batch, self.slot_pages
             )
             self._step = jax.jit(bundle.decode_step_paged)
-            self._chunk_step = jax.jit(bundle.prefill_chunk_paged)
+
+            def prefill_chunk_paged(params, tokens, state, rows):
+                # ``rows (3, B)``: each row's valid tokens, slot and start,
+                # uploaded as one array.  Named for the compiled module.
+                return bundle.prefill_chunk_paged(params, tokens, state, *rows)
+
+            self._chunk_step = jax.jit(prefill_chunk_paged)
             self._chunked = True
         else:
             self.page_size = None
@@ -278,6 +286,7 @@ class ServingEngine:
             "decode_steps": 0,
             "prefill_steps": 0,
             "prefill_tokens": 0,
+            "prefill_rows": 0,
             "preemptions": 0,
             "eos_stops": 0,
             "faults": 0,
@@ -656,7 +665,11 @@ class ServingEngine:
     def _sync_bt(self):
         if self._paged and self._bt_dirty:
             with span("engine.sync_bt", self.phase_s):
-                self.state = dict(self.state, block_tables=jnp.asarray(self._bt))
+                # Placed like the table a step returns, so a step meets one
+                # argument state whether or not the table changed since the
+                # last step, and compiles once.
+                bt = jax.device_put(self._bt, self.state["block_tables"].sharding)
+                self.state = dict(self.state, block_tables=bt)
             self._bt_dirty = False
 
     # ---- paged bookkeeping ----------------------------------------------
@@ -773,17 +786,25 @@ class ServingEngine:
 
     def _prefill_tick(self):
         """One scheduler iteration's prefill work: split the token budget
-        FCFS across prefilling slots and run a single batched chunk step.
+        FCFS across prefilling requests and run a single batched chunk step.
         Its span notes the step's valid and padded (``max_batch x chunk``)
-        tokens."""
+        tokens, its rows holding tokens and the requests they belong to."""
         with span("engine.prefill", self.phase_s) as sp:
-            valid = self._prefill_dispatch()
+            valid, rows, requests = self._prefill_dispatch()
             if valid:
                 sp.note(valid_tokens=valid,
-                        padded_tokens=self.max_batch * self.prefill_chunk)
+                        padded_tokens=self.max_batch * self.prefill_chunk,
+                        rows=rows, requests=requests)
 
-    def _prefill_dispatch(self) -> int:
-        """Build the chunk batch and dispatch it; returns its valid tokens."""
+    def _prefill_dispatch(self) -> tuple[int, int, int]:
+        """Build the chunk batch and dispatch it; returns its valid tokens,
+        the rows holding them and the requests those rows serve.
+
+        Rows are not slots.  First pass: each prefilling request, FCFS by
+        uid, gets one chunk in its own slot's row.  Second pass (paged
+        mode): the rows still free carry later chunks of the same requests,
+        FCFS, each starting where the request's previous row ends.  The
+        token budget caps the total either way."""
         self._fire("prefill_tick")
         prefilling = [
             (i, r) for i, r in enumerate(self.slots)
@@ -793,41 +814,67 @@ class ServingEngine:
         # into a lower slot must not preempt an older request's budget.
         prefilling.sort(key=lambda t: t[1].uid)
         if not prefilling:
-            return 0
+            return 0, 0, 0
         n_decode = sum(
             1 for r in self.slots if r is not None and not self._prefilling(r)
         )
+        B, C = self.max_batch, self.prefill_chunk
         if self.token_budget is None:
-            budget = len(prefilling) * self.prefill_chunk
+            budget = B * C
         else:
             # Decode slots reserve their token first; prefill gets the rest.
             # budget can hit 0 only while something is decoding (the budget
             # is >= 1), so prefill never deadlocks: decode completions free
             # budget on a later iteration.
             budget = max(self.token_budget - n_decode, 0)
-        C = self.prefill_chunk
-        tokens = np.zeros((self.max_batch, C), np.int32)
-        n_valid = np.zeros((self.max_batch,), np.int32)
-        for i, req in prefilling:
-            remaining = len(req._tokens) - 1 - req._filled
-            a = min(remaining, C, budget)
+        tokens = np.zeros((B, C), np.int32)
+        n_valid = np.zeros((B,), np.int32)
+        row_slot = np.arange(B, dtype=np.int32)
+        row_start = np.zeros((B,), np.int32)
+        granted = dict.fromkeys((i for i, _ in prefilling), 0)
+
+        def place(row, i, req):
+            nonlocal budget
+            start = req._filled + granted[i]
+            a = min(len(req._tokens) - 1 - start, C, budget)
             if a <= 0:
-                continue
-            tokens[i, :a] = req._tokens[req._filled:req._filled + a]
-            n_valid[i] = a
+                return False
+            tokens[row, :a] = req._tokens[start:start + a]
+            n_valid[row], row_slot[row], row_start[row] = a, i, start
+            granted[i] += a
             budget -= a
-        if not n_valid.any():
-            return 0
-        self._sync_bt()
-        _, self.state = self._chunk_step(
-            self.params, jnp.asarray(tokens), self.state, jnp.asarray(n_valid)
-        )
+            return True
+
+        for i, req in prefilling:
+            place(i, i, req)
+        if self._paged:
+            free = iter(np.flatnonzero(n_valid == 0))
+            row = next(free, None)
+            for i, req in prefilling:
+                while row is not None and place(row, i, req):
+                    row = next(free, None)
         valid = int(n_valid.sum())
+        if not valid:
+            return 0, 0, 0
+        self._sync_bt()
+        step_rows = n_valid
+        if self._paged:
+            for row in np.flatnonzero(n_valid):
+                i, lo = row_slot[row], row_start[row]
+                hi = lo + n_valid[row] - 1
+                assert (self._bt[i, lo // self.page_size:hi // self.page_size + 1]
+                        != self.NULL).all(), f"row {row}: unmapped prompt page"
+            step_rows = np.stack([n_valid, row_slot, row_start])
+        _, self.state = self._chunk_step(
+            self.params, jnp.asarray(tokens), self.state, jnp.asarray(step_rows)
+        )
+        n_rows = int(np.count_nonzero(n_valid))
         self.counters["prefill_steps"] += 1
         self.counters["prefill_tokens"] += valid
+        self.counters["prefill_rows"] += n_rows
         for i, req in prefilling:
-            req._filled += int(n_valid[i])
-            req._cached += int(n_valid[i])
+            req._filled += granted[i]
+            req._cached += granted[i]
             if not self._prefilling(req):
                 # Last prompt token is fed by the slot's first decode step.
                 req._next_token = int(req._tokens[-1])
@@ -843,7 +890,7 @@ class ServingEngine:
                     # the slot's prefill allocation; its first decode waits
                     # for the next iteration so the budget cap holds.
                     self._hold_decode.add(i)
-        return valid
+        return valid, n_rows, sum(1 for g in granted.values() if g)
 
     # ---- token-by-token fallback (families without prefill_chunk) -------
 

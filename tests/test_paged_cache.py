@@ -12,6 +12,8 @@ prompt + generated tokens), and retirement happens at the last writable
 position — never past it.
 """
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -523,3 +525,317 @@ def test_engine_preemption_keeps_shared_prefix_pages():
     step = _legacy_step(bundle)
     assert_greedy_chain_matches(bundle, params, a, 2, 64, step)
     assert_greedy_chain_matches(bundle, params, b, 2, 64, step)
+
+
+# ---------------------------------------------------------------------------
+# packed prefill rows: a row carries any slot's chunk at any start
+# ---------------------------------------------------------------------------
+
+
+def _paged_state(bundle, *, ps, W, slots, n_pages, pages):
+    """Fresh pool with ``pages[s]`` mapped (in that order) for slot ``s``."""
+    bt = np.full((slots, W), n_pages, np.int32)
+    for s, pg in enumerate(pages):
+        bt[s, : len(pg)] = pg
+    state = bundle.init_paged_state(n_pages, ps, slots, W)
+    return dict(state, block_tables=jnp.asarray(bt))
+
+
+def _chunk_rows(C, rows):
+    """``(tokens, n_valid)`` of a step whose row ``b`` holds ``rows[b]``."""
+    t = np.zeros((len(rows), C), np.int32)
+    nv = np.zeros((len(rows),), np.int32)
+    for b, toks in enumerate(rows):
+        t[b, : len(toks)] = toks
+        nv[b] = len(toks)
+    return jnp.asarray(t), jnp.asarray(nv)
+
+
+def _prefill_gather_before_write(params, token_ids, cache, n_valid, *, cfg):
+    """The one-row-per-slot prefill step as it was before rows were packed:
+    gather the resident view from the pre-chunk pool, clamped to the
+    pre-chunk length, attend, then write the chunk.  The oracle for the
+    step's default arguments."""
+    from repro.core.api import sp_prefill
+    from repro.models import transformer as T
+    from repro.models.attention import _project_qkv
+    from repro.models.layers import dense
+    from repro.serving.kv_cache import (
+        gather_pages,
+        gather_positions,
+        view_indices,
+        write_coords,
+    )
+
+    B, C = token_ids.shape
+    n_pages, ps = cache["pos"].shape
+    bt, length = cache["block_tables"], cache["len"]
+    offs = jnp.arange(C, dtype=jnp.int32)[None, :]
+    positions = length[:, None] + offs
+    page, off = write_coords(bt, positions, offs < n_valid[:, None], n_pages, ps)
+    flat = view_indices(bt, ps, lengths=length)
+    old_pos = gather_positions(cache["pos"], flat)
+    x = params["embed"]["table"][token_ids].astype(jnp.dtype(cfg.dtype))
+
+    def body(x, xs):
+        p_l, kc, vc = xs
+        h = T.apply_norm(p_l["ln1"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
+        q, k, v = _project_qkv(p_l["attn"], h, positions, cfg, pctx=PCTX)
+        out = sp_prefill(
+            q, k, v, positions, gather_pages(kc, flat), gather_pages(vc, flat),
+            old_pos, positions, pctx=PCTX, table_pages=bt.shape[1],
+        )
+        x = x + dense(p_l["attn"]["wo"], out.reshape(B, C, -1), jnp.dtype(cfg.dtype))
+        h = T.apply_norm(p_l["ln2"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
+        x = x + T.mlp(p_l["mlp"], h, mlp_type=cfg.mlp_type,
+                      compute_dtype=jnp.dtype(cfg.dtype))
+        kc = kc.at[page, off].set(k.astype(kc.dtype), mode="drop")
+        vc = vc.at[page, off].set(v.astype(vc.dtype), mode="drop")
+        return x, (kc, vc)
+
+    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
+    x = T.apply_norm(params["final_norm"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
+    last = x[jnp.arange(B), jnp.clip(n_valid - 1, 0, C - 1)]
+    logits = jnp.einsum(
+        "bd,dv->bv", last.astype(jnp.dtype(cfg.dtype)),
+        T._lm_head_w(params, cfg).astype(jnp.dtype(cfg.dtype)),
+    )
+    return logits, dict(
+        cache, k=ks, v=vs,
+        pos=cache["pos"].at[page, off].set(positions, mode="drop"),
+        len=length + n_valid,
+    )
+
+
+def test_prefill_default_rows_match_gather_before_write_bit_for_bit():
+    """With its default arguments (row = slot, start = ``len``) the
+    write-then-gather step gives the old gather-then-write result bit for
+    bit: logits, K/V, positions and lengths, over steps that start inside a
+    partly used page, with one slot idle in between."""
+    cfg, bundle, params = _setup()
+    ps, W, C = 4, 6, 5
+    state = _paged_state(bundle, ps=ps, W=W, slots=2, n_pages=12,
+                         pages=[[9, 2, 7, 4, 0, 11], [3, 8, 1, 5]])
+    ref = state
+    step = jax.jit(bundle.prefill_chunk_paged)
+    old = jax.jit(partial(_prefill_gather_before_write, cfg=cfg))
+    rng = np.random.default_rng(11)
+    for rows in ([5, 3], [5, 0], [2, 5], [4, 4]):
+        t, nv = _chunk_rows(C, [rng.integers(1, 90, n) for n in rows])
+        got_logits, state = step(params, t, state, nv)
+        want_logits, ref = old(params, t, ref, nv)
+        for b, n in enumerate(rows):
+            if n:
+                np.testing.assert_array_equal(
+                    np.asarray(got_logits[b]), np.asarray(want_logits[b]))
+        for key in ("k", "v", "pos", "len"):
+            np.testing.assert_array_equal(
+                np.asarray(state[key]), np.asarray(ref[key]), err_msg=key)
+
+
+def test_packed_rows_of_one_slot_equal_sequential_steps():
+    """One step whose two rows carry consecutive chunks of one slot (starts
+    ``s`` and ``s + C``, ``s`` inside a page) equals two sequential steps:
+    the second row sees the first row's K/V, written in the same step."""
+    cfg, bundle, params = _setup()
+    ps, W, C = 4, 6, 5
+    base = _paged_state(bundle, ps=ps, W=W, slots=2, n_pages=12,
+                        pages=[[9, 2, 7, 4, 0, 11], [3, 8]])
+    step = jax.jit(bundle.prefill_chunk_paged)
+    rng = np.random.default_rng(5)
+    head, c1, c2 = (rng.integers(1, 90, n) for n in (3, C, 4))
+    t, nv = _chunk_rows(C, [head, []])
+    _, base = step(params, t, base, nv)  # slot 0 at len 3: mid-page
+    s = 3
+
+    seq = base
+    t, nv = _chunk_rows(C, [c1, []])
+    _, seq = step(params, t, seq, nv)
+    t, nv = _chunk_rows(C, [c2, []])
+    seq_logits, seq = step(params, t, seq, nv)
+
+    t, nv = _chunk_rows(C, [c1, c2])
+    packed_logits, packed = step(
+        params, t, base, nv, jnp.asarray([0, 0], np.int32),
+        jnp.asarray([s, s + C], np.int32),
+    )
+    assert np.asarray(packed["len"]).tolist() == [s + C + 4, 0]
+    for key in ("pos", "len"):
+        np.testing.assert_array_equal(np.asarray(packed[key]), np.asarray(seq[key]),
+                                      err_msg=key)
+    bt = np.asarray(base["block_tables"])[0]
+    slots = np.arange(s, s + C + 4)
+    page, off = bt[slots // ps], slots % ps
+    for key in ("k", "v"):
+        np.testing.assert_array_equal(
+            np.asarray(packed[key])[:, page, off], np.asarray(seq[key])[:, page, off],
+            err_msg=key)
+    np.testing.assert_allclose(np.asarray(packed_logits[1]), np.asarray(seq_logits[0]),
+                               atol=1e-5, rtol=1e-5)
+
+
+def test_packed_empty_row_leaves_pool_and_len_untouched():
+    """A row without tokens writes nothing and moves no length, whatever
+    slot and start it names — here a mapped slot, at a start beyond its
+    length."""
+    cfg, bundle, params = _setup()
+    ps, W, C = 4, 4, 4
+    state = _paged_state(bundle, ps=ps, W=W, slots=2, n_pages=8,
+                         pages=[[6, 1, 4], [2, 7, 0]])
+    step = jax.jit(bundle.prefill_chunk_paged)
+    t, nv = _chunk_rows(C, [[5, 17, 3], [42, 9]])
+    _, state = step(params, t, state, nv)
+    t, nv = _chunk_rows(C, [[], [11, 63, 2, 8]])
+    _, want = step(params, t, state, nv)  # row 0 empty, default slot/start
+    _, got = step(params, t, state, nv, jnp.asarray([1, 1], np.int32),
+                  jnp.asarray([7, 2], np.int32))
+    for key in ("k", "v", "pos", "len"):
+        np.testing.assert_array_equal(np.asarray(got[key]), np.asarray(want[key]),
+                                      err_msg=key)
+    t, nv = _chunk_rows(C, [[], []])
+    _, idle = step(params, t, state, nv, jnp.asarray([1, 0], np.int32),
+                   jnp.asarray([9, 1], np.int32))
+    for key in ("k", "v", "pos", "len"):
+        np.testing.assert_array_equal(np.asarray(idle[key]), np.asarray(state[key]),
+                                      err_msg=key)
+
+
+def _admitted_prompt_tokens(eng):
+    """Record, per admission, the prompt tokens left to prefill (what the
+    unpacked engine would count): every token fed after a prefix hit."""
+    seen = []
+    admit = eng._admit_into
+
+    def spy(i, qi, req):
+        ok = admit(i, qi, req)
+        if ok:
+            seen.append(len(req._tokens) - 1 - req._filled)
+        return ok
+
+    eng._admit_into = spy
+    return seen
+
+
+def test_engine_packs_a_lone_prompt_into_one_step():
+    """A request alone takes ``ceil((p-1)/(B*C))`` prefill steps: a 23-token
+    prompt at batch 4 and chunk 8 prefills in one step of three rows
+    (8 + 8 + 6 tokens, starting mid-page), and its chain is exact."""
+    cfg, bundle, params = _setup()
+    rng = np.random.default_rng(4)
+    eng = ServingEngine(bundle, params, max_batch=4, max_len=64, prefill_chunk=8,
+                        page_size=3)
+    req = eng.submit(rng.integers(1, 90, 23), max_new_tokens=5)
+    eng.run()
+    s = eng.stats()
+    assert s["prefill_steps"] == 1 and s["prefill_rows"] == 3
+    assert s["prefill_tokens"] == 22 and len(req.output) == 5
+    assert_greedy_chain_matches(bundle, params, req, 2, 64, _legacy_step(bundle))
+
+
+def test_engine_overlapping_prompts_each_get_a_row_while_one_decodes():
+    """Two prompts prefilling beside a decoding slot: every step gives each
+    at least one row (first pass), the older one takes the free rows
+    (second pass), and every chain is exact."""
+    cfg, bundle, params = _setup()
+    rng = np.random.default_rng(9)
+    eng = ServingEngine(bundle, params, max_batch=4, max_len=64, prefill_chunk=4,
+                        page_size=4)
+    short = eng.submit([3, 9], max_new_tokens=10)
+    eng.run(max_steps=1)  # short is decoding from here on
+    a = eng.submit(rng.integers(1, 90, 41), max_new_tokens=4)
+    b = eng.submit(rng.integers(1, 90, 37), max_new_tokens=4)
+    eng.run(max_steps=1)  # both admitted; a: rows 1, 0, 3 and b: row 2
+    assert (a.prefilled, b.prefilled) == (12, 4)
+    shared = 1
+    while eng._prefilling(a) and eng._prefilling(b):
+        fa, fb = a.prefilled, b.prefilled
+        eng.run(max_steps=1)
+        # a, older, takes the free rows first: three chunks while it lasts
+        assert a.prefilled - fa == min(12, 40 - fa) and b.prefilled - fb >= 1
+        shared += 1
+    assert shared >= 3
+    eng.run()
+    assert all(r.status == "done" for r in (short, a, b))
+    s = eng.stats()
+    assert s["prefill_tokens"] == 40 + 36 + 1
+    assert s["prefill_rows"] > s["prefill_steps"]
+    step = _legacy_step(bundle)
+    for r in (short, a, b):
+        assert_greedy_chain_matches(bundle, params, r, 2, 64, step)
+
+
+def test_engine_preemption_re_prefill_exact_under_packing():
+    """A preempted request re-prefills its prompt and output in packed rows
+    and resumes exactly; the prefill tokens are what every admission had
+    left to prefill, no more and no fewer."""
+    cfg, bundle, params = _setup()
+    rng = np.random.default_rng(0)
+    eng = ServingEngine(bundle, params, max_batch=4, max_len=64, prefill_chunk=4,
+                        page_size=4, max_pages=12)
+    left = _admitted_prompt_tokens(eng)
+    reqs = [eng.submit(rng.integers(1, 90, 17), max_new_tokens=14) for _ in range(2)]
+    eng.run()
+    s = eng.stats()
+    assert s["preemptions"] >= 1, "pool was sized to force a preemption"
+    assert s["prefill_rows"] > s["prefill_steps"], "packing never engaged"
+    assert s["prefill_tokens"] == sum(left)
+    step = _legacy_step(bundle)
+    for r in reqs:
+        assert len(r.output) == 14
+        assert_greedy_chain_matches(bundle, params, r, 2, 64, step)
+
+
+def test_engine_prefix_warm_hit_packs_the_miss_suffix():
+    """A warm request whose prompt shares two resident pages prefills only
+    its miss suffix, in packed rows from the hit boundary; cold and warm
+    chains are exact."""
+    cfg, bundle, params = _setup()
+    rng = np.random.default_rng(7)
+    base = list(rng.integers(1, 90, 25))
+    fork = base[:16] + list(rng.integers(1, 90, 30))
+
+    eng = _prefix_engine(bundle, params, max_batch=4, prefill_chunk=4)
+    left = _admitted_prompt_tokens(eng)
+    cold = eng.submit(base, max_new_tokens=4)
+    eng.run()
+    steps = eng.counters["prefill_steps"]
+    warm = eng.submit(fork, max_new_tokens=4)
+    eng.run()
+    assert left == [24, 45 - 16]
+    assert eng.counters["prefill_tokens"] == 24 + 29
+    assert eng.counters["prefill_steps"] - steps == 2  # ceil(29 / (4 * 4))
+    step = _legacy_step(bundle)
+    assert_greedy_chain_matches(bundle, params, cold, 2, 64, step)
+    assert_greedy_chain_matches(bundle, params, warm, 2, 64, step)
+
+
+def test_engine_token_budget_caps_packed_rows():
+    """Metered packing: no iteration spends more than the token budget,
+    prefill rows and decode tokens together."""
+    cfg, bundle, params = _setup()
+    rng = np.random.default_rng(2)
+    eng = ServingEngine(bundle, params, max_batch=4, max_len=64, prefill_chunk=4,
+                        page_size=4, token_budget=10)
+    short = eng.submit([3, 9], max_new_tokens=12)
+    eng.run(max_steps=1)
+    reqs = [short] + [eng.submit(rng.integers(1, 90, n), max_new_tokens=4)
+                      for n in (29, 22)]
+    spent_max = 0
+    for _ in range(200):
+        eng._admit()
+        if all(s is None for s in eng.slots) and not eng.queue:
+            break
+        pre0 = eng.counters["prefill_tokens"]
+        dec0 = sum(len(r.output) for r in reqs)
+        eng._prefill_tick()
+        eng._decode_once()
+        spent = (eng.counters["prefill_tokens"] - pre0
+                 + sum(len(r.output) for r in reqs) - dec0)
+        assert spent <= 10, f"iteration spent {spent} tokens, budget is 10"
+        spent_max = max(spent_max, spent)
+    assert spent_max == 10
+    assert eng.counters["prefill_rows"] > eng.counters["prefill_steps"]
+    step = _legacy_step(bundle)
+    for r in reqs:
+        assert r.t_done is not None
+        assert_greedy_chain_matches(bundle, params, r, 2, 64, step)

@@ -462,8 +462,9 @@ def test_paged_steps_lower_under_stable_names():
     eng = ServingEngine(bundle, params, max_batch=2, max_len=64, prefill_chunk=8,
                         page_size=8)
     B, C = 2, 8
+    rows = jnp.zeros((3, B), jnp.int32)  # each row's valid tokens, slot, start
     pre = eng._chunk_step.lower(params, jnp.zeros((B, C), jnp.int32), eng.state,
-                                jnp.zeros((B,), jnp.int32)).as_text()
+                                rows).as_text()
     dec = eng._step.lower(params, jnp.zeros((B,), jnp.int32), eng.state,
                           jnp.zeros((B,), bool)).as_text()
     assert "module @jit_prefill_chunk_paged" in pre
