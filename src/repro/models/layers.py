@@ -51,7 +51,8 @@ def constrain(x, pctx, spec_entries):
 def dense_init(key, d_in: int, d_out: int, *, bias: bool = False, dtype="float32", scale=None):
     if scale is None:
         scale = 1.0 / np.sqrt(d_in)
-    p = {"w": jax.random.normal(key, (d_in, d_out), _dtype(dtype)) * scale}
+    # Cast after scaling: a NumPy float64 scale would promote the leaf.
+    p = {"w": (jax.random.normal(key, (d_in, d_out), _dtype(dtype)) * scale).astype(_dtype(dtype))}
     if bias:
         p["b"] = jnp.zeros((d_out,), _dtype(dtype))
     return p

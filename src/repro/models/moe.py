@@ -1,18 +1,27 @@
 """Mixture-of-Experts FFN (qwen3-moe 128e top-8, llama4-scout 16e top-1).
 
-GShard/Switch-style capacity dispatch, adapted for the (data, model) mesh:
+Routing is the published one in float32: logits over every expert, top-k,
+gates renormalized over the k picked (qwen "norm_topk_prob").
 
-  * routing groups are **per batch row** (group = one sequence), so the
-    position-in-expert cumsum runs along the sequence dim only — no global
-    token reordering;
-  * the dispatch buffer is ``(B, E, C, d)`` with E sharded over the ``model``
-    axis (expert parallelism) and B over ``data`` — the scatter/gather to and
-    from sequence-sharded activations is XLA SPMD's all-to-all;
-  * top-k gates are renormalized (qwen "norm_topk_prob"); dropped tokens
-    (beyond capacity C = ceil(S*K/E * capacity_factor)) contribute zero;
-  * the Switch load-balancing auxiliary loss is returned for the trainer.
+  * single device (``pctx`` inactive): **dropless** grouped dispatch.  The
+    (token, k) entries of valid tokens are sorted by expert, the experts run
+    as grouped matmuls over their contiguous groups (``jax.lax.ragged_dot``),
+    and the outputs are unsorted and gate-weighted.  No capacity, no drops;
+    masked tokens (padding rows of a prefill chunk, inactive decode slots)
+    get no entries and output zero.  Shapes stay static: masked entries sort
+    to a tail that no group covers.
+  * distributed (``pctx`` active): GShard/Switch capacity dispatch over the
+    (data, model) mesh with an all-to-all each way (``_moe_ffn_a2a``), or
+    replicated tokens and a psum for an unshardable sequence (decode).
+    Entries beyond capacity C = ceil(S*K/E * capacity_factor) are dropped.
 
-An optional shared expert (llama4) runs densely alongside the routed experts.
+The Switch load-balancing auxiliary loss is returned for the trainer.  An
+optional shared expert (llama4) runs densely alongside the routed experts.
+
+Ops carry the name scopes ``moe_route`` (router, top-k, sort),
+``moe_gmm`` (the grouped matmuls and the SwiGLU between them) and
+``moe_combine`` (unsort, gate-weighted sum), so a device trace can attribute
+the expert layer's time.
 """
 
 from __future__ import annotations
@@ -21,21 +30,20 @@ import jax
 import jax.numpy as jnp
 from repro.core.compat import shard_map
 
-from repro.models.layers import constrain as _constrain, dense_init, mlp, mlp_init
+from repro.models.layers import dense_init, mlp, mlp_init
 
-__all__ = ["moe_init", "moe_ffn"]
+__all__ = ["moe_init", "moe_ffn", "moe_counters", "moe_tally"]
 
 
 def moe_init(key, cfg):
     E, d, f = cfg.n_experts, cfg.d_model, cfg.moe_d_ff
+    dt = jnp.dtype(cfg.param_dtype)
     ks = jax.random.split(key, 5)
-    scale = 1.0 / jnp.sqrt(d)
     p = {
         "router": dense_init(ks[0], d, E, dtype=cfg.param_dtype),
-        "wg": jax.random.normal(ks[1], (E, d, f), jnp.dtype(cfg.param_dtype)) * scale,
-        "wu": jax.random.normal(ks[2], (E, d, f), jnp.dtype(cfg.param_dtype)) * scale,
-        "wd": jax.random.normal(ks[3], (E, f, d), jnp.dtype(cfg.param_dtype))
-        * (1.0 / jnp.sqrt(f)),
+        "wg": (jax.random.normal(ks[1], (E, d, f), dt) * d**-0.5).astype(dt),
+        "wu": (jax.random.normal(ks[2], (E, d, f), dt) * d**-0.5).astype(dt),
+        "wd": (jax.random.normal(ks[3], (E, f, d), dt) * f**-0.5).astype(dt),
     }
     if cfg.n_shared_experts:
         p["shared"] = mlp_init(
@@ -45,15 +53,32 @@ def moe_init(key, cfg):
     return p
 
 
-def _capacity(cfg, S: int) -> int:
-    E, K = cfg.n_experts, cfg.n_experts_per_token
-    c = int(S * K / E * cfg.capacity_factor + 0.999)
-    c = max(8, -(-c // 8) * 8)  # round up to a multiple of 8
-    return min(c, S * K)
+def moe_counters():
+    """Device counters of the single-device expert layer, carried in a serve
+    state: expert rows of valid tokens (``routed``), experts with at least
+    one row summed over layers and steps (``touched``), and the steps that
+    ran the layer (``steps``).  uint32: they wrap at 2**32, so read them as
+    differences modulo 2**32."""
+    return {k: jnp.zeros((), jnp.uint32) for k in ("routed", "touched", "steps")}
 
 
-def moe_ffn(p, x, cfg, pctx):
-    """x (B,S,d) -> (y (B,S,d), aux_loss scalar).
+def moe_tally(counters, counts):
+    """``counters`` after one step whose layers' counts sum to ``counts``."""
+    return {
+        "routed": counters["routed"] + counts["routed"].astype(jnp.uint32),
+        "touched": counters["touched"] + counts["touched"].astype(jnp.uint32),
+        "steps": counters["steps"] + jnp.uint32(1),
+    }
+
+
+def moe_ffn(p, x, cfg, pctx, valid=None):
+    """x (B,S,d) -> (y (B,S,d), aux_loss scalar, counts).
+
+    ``valid (B,S)`` (bool, optional) marks the real tokens; the others route
+    to no expert and output zero (single device; the distributed paths route
+    every token).  ``counts``: ``{"routed", "touched"}`` int32 scalars of the
+    single-device layer (expert rows run, experts with at least one row), or
+    None on the distributed paths.
 
     Dispatch impl:
       * distributed (pctx active): shard_map all-to-all expert parallelism —
@@ -62,75 +87,66 @@ def moe_ffn(p, x, cfg, pctx):
         volume is O(tokens * K * d) — the production EP pattern.  (The naive
         pjit scatter formulation all-reduces the (B,E,C,d) capacity buffer:
         measured 17 s collective term on qwen3-moe train_4k; §Perf iter 1.)
-      * single-device: dense capacity dispatch (same math, no comms).
+      * single-device: dropless sort + grouped matmul (``_moe_ffn_dropless``).
     """
     if pctx is not None and pctx.active:
         if x.shape[1] % pctx.sp_degree == 0:
-            return _moe_ffn_a2a(p, x, cfg, pctx)
+            return (*_moe_ffn_a2a(p, x, cfg, pctx), None)
         # seq dim not shardable (decode S=1): tokens replicated over the EP
         # axes, each rank computes only entries routed to ITS experts, psum.
-        return _moe_ffn_replicated_seq(p, x, cfg, pctx)
-    return _moe_ffn_dense(p, x, cfg, pctx)
+        return (*_moe_ffn_replicated_seq(p, x, cfg, pctx), None)
+    return _moe_ffn_dropless(p, x, cfg, valid)
 
 
-def _moe_ffn_dense(p, x, cfg, pctx):
-    """Single-device (or fully replicated) capacity dispatch."""
+def _moe_ffn_dropless(p, x, cfg, valid):
+    """Single-device dropless dispatch: sort entries by expert, grouped
+    matmuls over the groups, unsort and combine in float32."""
     B, S, d = x.shape
     E, K = cfg.n_experts, cfg.n_experts_per_token
-    C = _capacity(cfg, S)
+    N = B * S
     dt = jnp.dtype(cfg.dtype)
+    xf = x.reshape(N, d)
+    ok = jnp.ones((N,), bool) if valid is None else valid.reshape(N)
 
-    # --- routing (fp32) -----------------------------------------------------
-    logits = jnp.einsum(
-        "bsd,de->bse", x.astype(jnp.float32), p["router"]["w"].astype(jnp.float32)
-    )
-    probs = jax.nn.softmax(logits, axis=-1)  # (B,S,E)
-    gate_vals, expert_idx = jax.lax.top_k(logits, K)  # (B,S,K)
-    gates = jax.nn.softmax(gate_vals, axis=-1)  # renormalize over the K picked
+    with jax.named_scope("moe_route"):
+        logits = jnp.einsum(
+            "nd,de->ne", xf.astype(jnp.float32), p["router"]["w"].astype(jnp.float32)
+        )
+        probs = jax.nn.softmax(logits, axis=-1)  # (N,E)
+        gate_vals, expert_idx = jax.lax.top_k(logits, K)  # (N,K)
+        gates = jax.nn.softmax(gate_vals, axis=-1)  # renormalize over the K picked
+        # Entries of masked tokens take expert id E: they sort last, past
+        # every group, and no matmul row belongs to them.
+        flat_e = jnp.where(ok[:, None], expert_idx, E).reshape(N * K)
+        order = jnp.argsort(flat_e, stable=True)
+        sizes = jnp.sum(flat_e[:, None] == jnp.arange(E)[None, :], axis=0, dtype=jnp.int32)
+        xs = xf.astype(dt)[order // K]  # (N*K, d), grouped by expert
 
-    # --- position-in-expert within each sequence (row) ----------------------
-    flat_e = expert_idx.reshape(B, S * K)  # (B, T) entries, T = S*K
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)  # (B,T,E)
-    pos_in_e = jnp.cumsum(onehot, axis=1) - onehot  # entries before me
-    pos = jnp.sum(pos_in_e * onehot, axis=-1)  # (B,T)
-    keep = pos < C
-    pos_c = jnp.where(keep, pos, C - 1)
+    with jax.named_scope("moe_gmm"):
+        g = jax.lax.ragged_dot(xs, p["wg"].astype(dt), sizes)
+        u = jax.lax.ragged_dot(xs, p["wu"].astype(dt), sizes)
+        ys = jax.lax.ragged_dot(jax.nn.silu(g) * u, p["wd"].astype(dt), sizes)
 
-    # --- dispatch: scatter token copies into (E, C, d) per row ---------------
-    xe = jnp.repeat(x[:, :, None, :], K, axis=2).reshape(B, S * K, d).astype(dt)
-    xe = xe * keep[..., None].astype(dt)
-
-    def row_dispatch(tok, eid, pp):
-        buf = jnp.zeros((E, C, tok.shape[-1]), dt)
-        return buf.at[eid, pp].add(tok)
-
-    buf = jax.vmap(row_dispatch)(xe, flat_e, pos_c)  # (B,E,C,d)
-    buf = _constrain(buf, pctx, (pctx.data_axis if pctx else None, pctx.seq_spec() if pctx else None, None, None))
-
-    # --- expert FFN (swiglu), E sharded over the model axis ------------------
-    g = jnp.einsum("becd,edf->becf", buf, p["wg"].astype(dt))
-    u = jnp.einsum("becd,edf->becf", buf, p["wu"].astype(dt))
-    yexp = jnp.einsum("becf,efd->becd", jax.nn.silu(g) * u, p["wd"].astype(dt))
-
-    # --- combine: gather each entry's expert output, gate-weighted -----------
-    def row_gather(ybuf, eid, pp):
-        return ybuf[eid, pp]
-
-    y_ent = jax.vmap(row_gather)(yexp, flat_e, pos_c)  # (B,T,d)
-    y_ent = y_ent * keep[..., None].astype(dt)
-    y_ent = y_ent.reshape(B, S, K, d)
-    y = jnp.einsum("bskd,bsk->bsd", y_ent.astype(jnp.float32), gates).astype(dt)
+    with jax.named_scope("moe_combine"):
+        inv = jnp.zeros_like(order).at[order].set(jnp.arange(N * K, dtype=order.dtype))
+        y_ent = ys[inv].reshape(N, K, d).astype(jnp.float32)
+        # where, not a product: rows past the last group hold no result.
+        y_ent = jnp.where(ok[:, None, None], y_ent, 0.0)
+        y = jnp.einsum("nkd,nk->nd", y_ent, gates).astype(dt).reshape(B, S, d)
 
     if cfg.n_shared_experts:
         y = y + mlp(p["shared"], x, mlp_type="swiglu", compute_dtype=dt)
 
-    # --- Switch aux loss ------------------------------------------------------
-    importance = jnp.mean(probs, axis=(0, 1))  # (E,)
-    assigned = jnp.mean(
-        jnp.sum(jax.nn.one_hot(expert_idx, E, dtype=jnp.float32), axis=2), axis=(0, 1)
-    )  # fraction of entries routed to each expert
+    # --- Switch aux loss over valid tokens ------------------------------------
+    w = ok.astype(jnp.float32)[:, None]
+    n_ok = jnp.maximum(jnp.sum(w), 1.0)
+    importance = jnp.sum(probs * w, axis=0) / n_ok  # (E,)
+    assigned = jnp.sum(
+        jnp.sum(jax.nn.one_hot(expert_idx, E, dtype=jnp.float32), axis=1) * w, axis=0
+    ) / n_ok  # fraction of entries routed to each expert
     aux = E * jnp.sum(importance * assigned) / K
-    return y, aux
+    counts = {"routed": jnp.sum(sizes), "touched": jnp.sum(sizes > 0).astype(jnp.int32)}
+    return y, aux, counts
 
 
 # ---------------------------------------------------------------------------

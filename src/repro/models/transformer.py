@@ -40,7 +40,7 @@ from repro.models.layers import (
     mlp_init,
     norm_init,
 )
-from repro.models.moe import moe_ffn, moe_init
+from repro.models.moe import moe_counters, moe_ffn, moe_init, moe_tally
 
 __all__ = [
     "init_lm",
@@ -69,6 +69,28 @@ def _remat_policy(name):
     if name == "dots":
         return jax.checkpoint_policies.dots_with_no_batch_dims_saveable
     raise ValueError(name)
+
+
+def _ffn(p_l, h, cfg, pctx, valid=None):
+    """The layer's feed-forward: ``(y, aux, counts)``.  With experts, tokens
+    outside ``valid`` route to none (see ``moe_ffn``); dense layers return
+    no aux loss and no counts."""
+    if cfg.n_experts:
+        return moe_ffn(p_l["moe"], h, cfg, pctx, valid)
+    y = mlp(p_l["mlp"], h, mlp_type=cfg.mlp_type, compute_dtype=jnp.dtype(cfg.dtype))
+    return y, jnp.float32(0.0), None
+
+
+def _moe_zero(cache):
+    """Per-step expert counts to accumulate over the layer scan: only where
+    the serve state carries the expert counters (``moe_counters``)."""
+    if "moe" not in cache:
+        return None
+    return {"routed": jnp.int32(0), "touched": jnp.int32(0)}
+
+
+def _moe_add(acc, counts):
+    return None if acc is None else {k: acc[k] + counts[k] for k in acc}
 
 
 # ---------------------------------------------------------------------------
@@ -127,11 +149,7 @@ def _block(p_l, x, positions, cfg, pctx):
     )
     x = constrain(x, pctx, _act_spec(pctx))
     h = apply_norm(p_l["ln2"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
-    if cfg.n_experts:
-        y, aux = moe_ffn(p_l["moe"], h, cfg, pctx)
-    else:
-        y = mlp(p_l["mlp"], h, mlp_type=cfg.mlp_type, compute_dtype=jnp.dtype(cfg.dtype))
-        aux = jnp.float32(0.0)
+    y, aux, _ = _ffn(p_l, h, cfg, pctx)
     x = x + y
     x = constrain(x, pctx, _act_spec(pctx))
     return x, aux
@@ -242,10 +260,7 @@ def lm_prefill(params, tokens, positions, cache, prefix_embeds=None, *, cfg, pct
         )
         x = x + y
         h = apply_norm(p_l["ln2"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
-        if cfg.n_experts:
-            y, _ = moe_ffn(p_l["moe"], h, cfg, pctx)
-        else:
-            y = mlp(p_l["mlp"], h, mlp_type=cfg.mlp_type, compute_dtype=jnp.dtype(cfg.dtype))
+        y, _, _ = _ffn(p_l, h, cfg, pctx)
         x = constrain(x + y, pctx, _act_spec(pctx))
         return x, (new_cache["k"], new_cache["v"])
 
@@ -305,10 +320,7 @@ def lm_prefill_chunk(params, token_ids, cache, n_valid, *, cfg, pctx):
         )
         x = x + y
         h = apply_norm(p_l["ln2"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
-        if cfg.n_experts:
-            y, _ = moe_ffn(p_l["moe"], h, cfg, pctx)
-        else:
-            y = mlp(p_l["mlp"], h, mlp_type=cfg.mlp_type, compute_dtype=jnp.dtype(cfg.dtype))
+        y, _, _ = _ffn(p_l, h, cfg, pctx, valid)
         return x + y, (kc_l, vc_l)
 
     x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
@@ -337,17 +349,22 @@ def init_paged_decode_cache(
     """Page-pool serve state (see ``serving/kv_cache.py`` for the layout).
 
     Physical memory is ``n_pages * page_size`` tokens shared by every slot;
-    each slot's logical capacity is ``slot_pages * page_size``.  Under a mesh
+    each slot's logical capacity is ``slot_pages * page_size``.  A model
+    with experts on one device also carries ``moe``, the expert layer's
+    counters (``moe_counters``), which both paged steps advance.  Under a mesh
     the page dimension shards over the SP axes, so block tables wider than
     one device's page budget stripe the prompt across the ring.
     """
     from repro.serving.kv_cache import init_paged_cache
 
-    return init_paged_cache(
+    state = init_paged_cache(
         cfg.n_layers, cfg.n_kv_heads, cfg.head_dim, n_pages=n_pages,
         page_size=page_size, max_batch=max_batch, slot_pages=slot_pages,
         dtype=dtype or cfg.dtype, pctx=pctx,
     )
+    if cfg.n_experts and not (pctx is not None and pctx.active):
+        state["moe"] = moe_counters()
+    return state
 
 
 def lm_prefill_chunk_paged(
@@ -396,7 +413,8 @@ def lm_prefill_chunk_paged(
     pos_view = jnp.where(pos_view < row_start[:, None], pos_view, PAD_POS)
     x = params["embed"]["table"][token_ids].astype(jnp.dtype(cfg.dtype))
 
-    def body(x, xs):
+    def body(carry, xs):
+        x, n = carry
         p_l, kc_l, vc_l = xs
         h = apply_norm(p_l["ln1"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
         y, kc_l, vc_l = attention_prefill_chunk_paged(
@@ -406,13 +424,12 @@ def lm_prefill_chunk_paged(
         )
         x = x + y
         h = apply_norm(p_l["ln2"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
-        if cfg.n_experts:
-            y, _ = moe_ffn(p_l["moe"], h, cfg, pctx)
-        else:
-            y = mlp(p_l["mlp"], h, mlp_type=cfg.mlp_type, compute_dtype=jnp.dtype(cfg.dtype))
-        return x + y, (kc_l, vc_l)
+        y, _, counts = _ffn(p_l, h, cfg, pctx, valid)
+        return (x + y, _moe_add(n, counts)), (kc_l, vc_l)
 
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
+    (x, n), (ks, vs) = jax.lax.scan(
+        body, (x, _moe_zero(cache)), (params["layers"], cache["k"], cache["v"])
+    )
     x = apply_norm(params["final_norm"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
     last_idx = jnp.clip(n_valid - 1, 0, C - 1)
     last = x[jnp.arange(B), last_idx]
@@ -431,6 +448,8 @@ def lm_prefill_chunk_paged(
             (row_start + n_valid).astype(length.dtype), mode="drop"
         ),
     }
+    if n is not None:
+        new_cache["moe"] = moe_tally(cache["moe"], n)
     return logits, new_cache
 
 
@@ -463,7 +482,8 @@ def lm_decode_step_paged(params, token_ids, cache, active=None, *, cfg, pctx):
     )  # includes the new token
     x = params["embed"]["table"][token_ids[:, None]].astype(jnp.dtype(cfg.dtype))
 
-    def body(x, xs):
+    def body(carry, xs):
+        x, n = carry
         p_l, kc_l, vc_l = xs
         h = apply_norm(p_l["ln1"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
         y, kc_l, vc_l = attention_decode_paged(
@@ -473,13 +493,12 @@ def lm_decode_step_paged(params, token_ids, cache, active=None, *, cfg, pctx):
         )
         x = x + y
         h = apply_norm(p_l["ln2"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
-        if cfg.n_experts:
-            y, _ = moe_ffn(p_l["moe"], h, cfg, pctx)
-        else:
-            y = mlp(p_l["mlp"], h, mlp_type=cfg.mlp_type, compute_dtype=jnp.dtype(cfg.dtype))
-        return x + y, (kc_l, vc_l)
+        y, _, counts = _ffn(p_l, h, cfg, pctx, valid[:, None])
+        return (x + y, _moe_add(n, counts)), (kc_l, vc_l)
 
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
+    (x, n), (ks, vs) = jax.lax.scan(
+        body, (x, _moe_zero(cache)), (params["layers"], cache["k"], cache["v"])
+    )
     x = apply_norm(params["final_norm"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
     logits = jnp.einsum(
         "bsd,dv->bsv", x.astype(jnp.dtype(cfg.dtype)),
@@ -488,6 +507,8 @@ def lm_decode_step_paged(params, token_ids, cache, active=None, *, cfg, pctx):
     new_cache = {
         "k": ks, "v": vs, "pos": pos_pool, "block_tables": bt, "len": new_len,
     }
+    if n is not None:
+        new_cache["moe"] = moe_tally(cache["moe"], n)
     return logits, new_cache
 
 
@@ -526,10 +547,7 @@ def lm_decode_step(params, token_ids, cache, active=None, *, cfg, pctx):
         )
         x = x + y
         h = apply_norm(p_l["ln2"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
-        if cfg.n_experts:
-            y, _ = moe_ffn(p_l["moe"], h, cfg, pctx)
-        else:
-            y = mlp(p_l["mlp"], h, mlp_type=cfg.mlp_type, compute_dtype=jnp.dtype(cfg.dtype))
+        y, _, _ = _ffn(p_l, h, cfg, pctx, None if active is None else active[:, None])
         return x + y, (kc_l, vc_l)
 
     x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))

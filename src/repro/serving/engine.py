@@ -117,7 +117,12 @@ class ServingEngine:
         step (the static chunk width; prompt tails ride along as partial
         chunks, so there is exactly one compilation).  Paged mode fills the
         rows that prefilling requests leave empty with their later chunks,
-        so a request alone prefills ``max_batch`` chunks a step.
+        so a request alone prefills ``prefill_rows`` chunks a step.
+      * ``prefill_rows`` — paged mode: rows of a chunked-prefill step, so
+        ``prefill_rows x prefill_chunk`` prompt tokens a step at most.
+        Defaults to ``max_batch`` (one row per slot); fewer rows make a
+        step that carries a short prompt cheaper, and a long prompt then
+        takes more steps.
       * ``token_budget`` — meters *prefill*: an iteration grants prefilling
         slots at most ``token_budget - n_decoding`` tokens (FCFS).  Decode is
         indivisible — every decoding slot emits one token per iteration
@@ -134,7 +139,8 @@ class ServingEngine:
 
     def __init__(self, bundle, params, *, max_batch: int, max_len: int,
                  temperature: float = 0.0, seed: int = 0,
-                 prefill_chunk: int = 32, token_budget: int | None = None,
+                 prefill_chunk: int = 32, prefill_rows: int | None = None,
+                 token_budget: int | None = None,
                  page_size: int | None = None, max_pages: int | None = None,
                  preempt: bool = True, prefix_cache: bool = False,
                  fault_plan=None, audit_every: int = 0,
@@ -150,6 +156,12 @@ class ServingEngine:
             raise ValueError("snapshot_every needs snapshot_dir=")
         if prefill_chunk < 1:
             raise ValueError(f"prefill_chunk must be >= 1, got {prefill_chunk}")
+        if prefill_rows is not None and (page_size is None
+                                         or not 1 <= prefill_rows <= max_batch):
+            raise ValueError(
+                f"prefill_rows needs the paged KV cache and 1..max_batch "
+                f"({max_batch}) rows, got {prefill_rows}"
+            )
         if token_budget is not None and token_budget < 1:
             raise ValueError(f"token_budget must be >= 1, got {token_budget}")
         if prefix_cache and page_size is None:
@@ -163,6 +175,7 @@ class ServingEngine:
         self.max_len = max_len
         self.temperature = temperature
         self.prefill_chunk = prefill_chunk
+        self.prefill_rows = max_batch if prefill_rows is None else prefill_rows
         self.token_budget = token_budget
         self.key = jax.random.PRNGKey(seed)
         self.preempt = preempt
@@ -198,7 +211,7 @@ class ServingEngine:
             self._step = jax.jit(bundle.decode_step_paged)
 
             def prefill_chunk_paged(params, tokens, state, rows):
-                # ``rows (3, B)``: each row's valid tokens, slot and start,
+                # ``rows (3, R)``: each row's valid tokens, slot and start,
                 # uploaded as one array.  Named for the compiled module.
                 return bundle.prefill_chunk_paged(params, tokens, state, *rows)
 
@@ -787,13 +800,13 @@ class ServingEngine:
     def _prefill_tick(self):
         """One scheduler iteration's prefill work: split the token budget
         FCFS across prefilling requests and run a single batched chunk step.
-        Its span notes the step's valid and padded (``max_batch x chunk``)
+        Its span notes the step's valid and padded (``prefill_rows x chunk``)
         tokens, its rows holding tokens and the requests they belong to."""
         with span("engine.prefill", self.phase_s) as sp:
             valid, rows, requests = self._prefill_dispatch()
             if valid:
                 sp.note(valid_tokens=valid,
-                        padded_tokens=self.max_batch * self.prefill_chunk,
+                        padded_tokens=self.prefill_rows * self.prefill_chunk,
                         rows=rows, requests=requests)
 
     def _prefill_dispatch(self) -> tuple[int, int, int]:
@@ -801,7 +814,8 @@ class ServingEngine:
         the rows holding them and the requests those rows serve.
 
         Rows are not slots.  First pass: each prefilling request, FCFS by
-        uid, gets one chunk in its own slot's row.  Second pass (paged
+        uid, gets one chunk in its own slot's row (with fewer rows than
+        slots: in the next row, while rows last).  Second pass (paged
         mode): the rows still free carry later chunks of the same requests,
         FCFS, each starting where the request's previous row ends.  The
         token budget caps the total either way."""
@@ -818,7 +832,7 @@ class ServingEngine:
         n_decode = sum(
             1 for r in self.slots if r is not None and not self._prefilling(r)
         )
-        B, C = self.max_batch, self.prefill_chunk
+        B, C = self.prefill_rows, self.prefill_chunk
         if self.token_budget is None:
             budget = B * C
         else:
@@ -845,8 +859,10 @@ class ServingEngine:
             budget -= a
             return True
 
-        for i, req in prefilling:
-            place(i, i, req)
+        for n, (i, req) in enumerate(prefilling):
+            row = i if B == self.max_batch else n
+            if row < B:
+                place(row, i, req)
         if self._paged:
             free = iter(np.flatnonzero(n_valid == 0))
             row = next(free, None)
@@ -1075,6 +1091,7 @@ class ServingEngine:
             max_len=cfg["max_len"],
             temperature=cfg["temperature"],
             prefill_chunk=cfg["prefill_chunk"],
+            prefill_rows=cfg.get("prefill_rows"),
             token_budget=cfg["token_budget"],
             page_size=cfg["page_size"],
             max_pages=cfg["max_pages"],
@@ -1125,4 +1142,8 @@ class ServingEngine:
             out["pages"] = self.alloc.utilization()
         if self.prefix is not None:
             out["prefix"] = self.prefix.stats()
+        if "moe" in self.state:
+            # The expert layer's device counters (``models/moe.moe_counters``):
+            # reading them waits for the last dispatched step.
+            out["moe"] = {k: int(v) for k, v in jax.device_get(self.state["moe"]).items()}
         return out

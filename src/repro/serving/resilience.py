@@ -487,6 +487,7 @@ def export_serving_state(eng) -> dict:
             "max_len": eng.max_len,
             "temperature": eng.temperature,
             "prefill_chunk": eng.prefill_chunk,
+            "prefill_rows": eng.prefill_rows if eng._paged else None,
             "token_budget": eng.token_budget,
             "page_size": eng.page_size,
             "max_pages": eng.max_pages if eng._paged else None,

@@ -418,7 +418,8 @@ def check_scan_hybrid():
 
 
 def check_moe():
-    """a2a expert-parallel dispatch == dense capacity dispatch (fwd + grad)."""
+    """a2a expert-parallel dispatch == the single-device dropless layer
+    (fwd + grad), at a capacity that drops nothing."""
     from repro.models.config import ArchConfig
     from repro.models.moe import moe_init, moe_ffn
 
@@ -434,22 +435,22 @@ def check_moe():
     x = jnp.asarray(rng.standard_normal((B, S, cfg.d_model)), jnp.float32)
 
     dense_pctx = ParallelContext(mesh=None)
-    y_ref, aux_ref = jax.jit(lambda p, x: moe_ffn(p, x, cfg, dense_pctx))(p, x)
+    y_ref, aux_ref, _ = jax.jit(lambda p, x: moe_ffn(p, x, cfg, dense_pctx))(p, x)
 
     mesh = make_mesh((2, 4), ("data", "model"))
     pctx = ParallelContext(mesh=mesh, sp_axes=("model",), impl="xla")
-    y, aux = jax.jit(lambda p, x: moe_ffn(p, x, cfg, pctx))(p, x)
+    y, aux, _ = jax.jit(lambda p, x: moe_ffn(p, x, cfg, pctx))(p, x)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=2e-4, rtol=2e-4)
     np.testing.assert_allclose(float(aux), float(aux_ref), atol=1e-4, rtol=1e-4)
 
     w = jnp.asarray(rng.standard_normal(y_ref.shape), jnp.float32)
 
     def loss_a2a(p, x):
-        y, aux = moe_ffn(p, x, cfg, pctx)
+        y, aux, _ = moe_ffn(p, x, cfg, pctx)
         return jnp.sum(y * w) + aux
 
     def loss_dense(p, x):
-        y, aux = moe_ffn(p, x, cfg, dense_pctx)
+        y, aux, _ = moe_ffn(p, x, cfg, dense_pctx)
         return jnp.sum(y * w) + aux
 
     g1 = jax.jit(jax.grad(loss_a2a))(p, x)

@@ -839,3 +839,84 @@ def test_engine_token_budget_caps_packed_rows():
     for r in reqs:
         assert r.t_done is not None
         assert_greedy_chain_matches(bundle, params, r, 2, 64, step)
+
+
+def test_engine_fewer_prefill_rows_than_slots_exact():
+    """Two prefill rows under four slots: three prompts arrive together,
+    the first two take a row each and the third waits for a free row; no
+    step holds more than two rows of tokens, a prompt longer than two
+    chunks takes several steps, and every chain matches the default
+    engine's tokens and the oracle."""
+    cfg, bundle, params = _setup()
+    rng = np.random.default_rng(6)
+    prompts = [rng.integers(1, 90, n) for n in (5, 19, 7)]
+
+    def serve(**kw):
+        eng = ServingEngine(bundle, params, max_batch=4, max_len=64, prefill_chunk=4,
+                            page_size=4, **kw)
+        reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
+        eng.run(max_steps=1)
+        first = [r.prefilled for r in reqs]
+        eng.run()
+        return eng, reqs, first
+
+    eng, reqs, first = serve(prefill_rows=2)
+    assert first == [4, 4, 0]
+    s = eng.stats()
+    assert s["prefill_rows"] <= 2 * s["prefill_steps"]
+    assert s["prefill_steps"] >= -(-sum(len(p) - 1 for p in prompts) // 8)
+    _, ref, _ = serve()
+    assert [r.output for r in reqs] == [r.output for r in ref]
+    step = _legacy_step(bundle)
+    for r in reqs:
+        assert_greedy_chain_matches(bundle, params, r, 2, 64, step)
+
+
+def test_prefill_step_has_prefill_rows_rows():
+    """The chunked-prefill step runs on ``(prefill_rows, chunk)`` tokens
+    and ``(3, prefill_rows)`` row descriptors; an 11-token prompt fills
+    three rows of four (4 + 4 + 2)."""
+    cfg, bundle, params = _setup()
+    eng = ServingEngine(bundle, params, max_batch=4, max_len=64, prefill_chunk=4,
+                        page_size=4, prefill_rows=3)
+    shapes = []
+    real = eng._chunk_step
+
+    def spy(params, tokens, state, rows):
+        shapes.append((tokens.shape, rows.shape, np.asarray(rows)[0].tolist()))
+        return real(params, tokens, state, rows)
+
+    eng._chunk_step = spy
+    eng.submit(list(range(1, 12)), max_new_tokens=2)
+    eng.run(max_steps=1)
+    assert shapes == [((3, 4), (3, 3), [4, 4, 2])]
+
+
+@pytest.mark.parametrize("kw", [dict(prefill_rows=0, page_size=4),
+                                dict(prefill_rows=5, page_size=4),
+                                dict(prefill_rows=2, page_size=None)])
+def test_prefill_rows_validated(kw):
+    cfg, bundle, params = _setup()
+    with pytest.raises(ValueError, match="prefill_rows"):
+        ServingEngine(bundle, params, max_batch=4, max_len=64, prefill_chunk=4, **kw)
+
+
+def test_prefill_rows_survive_a_snapshot_restart(tmp_path):
+    """A restart from a snapshot keeps the step's row count and finishes
+    with the tokens of an uninterrupted run."""
+    cfg, bundle, params = _setup()
+    prompts = [list(range(1, 15)), list(range(20, 29))]
+    kw = dict(max_batch=4, max_len=64, prefill_chunk=4, page_size=4, prefill_rows=2)
+
+    eng = ServingEngine(bundle, params, snapshot_dir=str(tmp_path), **kw)
+    reqs = [eng.submit(p, max_new_tokens=5) for p in prompts]
+    eng.run(max_steps=2)
+    eng.snapshot()
+    eng2 = ServingEngine.from_snapshot(bundle, params, str(tmp_path))
+    assert eng2.prefill_rows == 2
+    got = {r.uid: list(r.output) for r in eng2.run()}
+
+    ref = ServingEngine(bundle, params, **kw)
+    want = [ref.submit(p, max_new_tokens=5) for p in prompts]
+    ref.run()
+    assert got == {r.uid: list(w.output) for r, w in zip(reqs, want)}

@@ -202,3 +202,36 @@ def test_kernels_named_in_compiled_v5e_hlo(one_chip, kernel):
                                              text, re.M)]
     assert any(re.search(rf"(^|_){kernel}(_|\.|$)", n) for n in names), (
         f"no custom call named {kernel} among {names}")
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode", "prefill_8_rows"])
+def test_moe_paged_steps_compile_for_v5e(one_chip, step):
+    """One layer of Qwen3-30B-A3B's paged serving steps at published widths
+    (32 q / 4 kv heads of 128, 128 experts of 768, top-8) at the serving
+    cell's shapes (batch 32, chunk 256, pages of 128, 36 a slot; prefill
+    on 32 rows, and on the cell's 8): the grouped matmuls, the sort and the
+    flash or paged-decode kernel compile, and the step's expert counters
+    come out of the program."""
+    from repro.configs.qwen3_moe_30b_a3b import CONFIG
+    from repro.core.api import ParallelContext
+    from repro.models import build_model
+
+    cfg = CONFIG.with_(n_layers=1, dtype="bfloat16", param_dtype="bfloat16")
+    bundle = build_model(cfg, ParallelContext(mesh=None, impl="pallas"))
+    B, C, pages, slot_pages = 32, 256, 64, 36
+    place = lambda t: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: _spec(a.shape, a.dtype, one_chip), t)
+    params = place(jax.eval_shape(bundle.init, jax.random.PRNGKey(0)))
+    state = place(jax.eval_shape(lambda: bundle.init_paged_state(pages, PAGE, B, slot_pages)))
+    assert set(state["moe"]) == {"routed", "touched", "steps"}
+    i32 = jnp.int32
+    if step.startswith("prefill"):
+        R = 8 if step == "prefill_8_rows" else B
+        fn = lambda p, t, s, r: bundle.prefill_chunk_paged(p, t, s, *r)  # noqa: E731
+        args = (params, _spec((R, C), i32, one_chip), state, _spec((3, R), i32, one_chip))
+    else:
+        fn = bundle.decode_step_paged
+        args = (params, _spec((B,), i32, one_chip), state, _spec((B,), jnp.bool_, one_chip))
+    out = jax.eval_shape(fn, *args)
+    assert set(out[1]["moe"]) == {"routed", "touched", "steps"}
+    _compiled_text(fn, *args)
