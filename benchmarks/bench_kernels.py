@@ -151,7 +151,7 @@ def _bench_decode(rng):
         n_pages = B * W + 1
         q = jnp.asarray(rng.standard_normal((B, 1, Hq, D)), jnp.float32)
         k_pool = jnp.asarray(
-            rng.standard_normal((n_pages, ps, Hkv, D)), jnp.float32
+            rng.standard_normal((1, n_pages, ps, Hkv, D)), jnp.float32
         )
         pos = np.full((n_pages, ps), PAD_POS, np.int32)
         bt = np.full((B, W), n_pages, np.int32)
@@ -177,7 +177,7 @@ def _bench_decode(rng):
         for impl, n in (("xla", 5), ("pallas_interpret", 1)):
             fn = jax.jit(
                 lambda q, impl=impl: paged_decode_attention(
-                    q, k_pool, k_pool, pos, bt, qp, lengths=lens, impl=impl
+                    q, k_pool, k_pool, pos, bt, qp, 0, lengths=lens, impl=impl
                 )[0]
             )
             dt = _time(fn, q, n=n)

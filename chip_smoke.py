@@ -12,7 +12,14 @@ One chip:
      through ``repro.launch.serve.main`` with ``--impl pallas`` on the paged
      KV cache: 8 requests sharing a 2048-token prefix;
   3. the same requests served with ``--impl xla``: the greedy tokens of a
-     request must agree over the first decode steps.
+     request must agree over the first decode steps;
+  4. the engine's two paged steps (``serving.engine.paged_steps``, the serve
+     state donated) compiled at the ``qwen3-longdoc`` benchmark cell's
+     shapes (the ``serving`` block of its configuration), bfloat16:
+     ``memory_analysis()`` alias and temp bytes of each, and the checks that
+     the whole K/V pool is aliased, so the steps update it in place, that no
+     layer of it is copied, and that the prefill step reads its layer into
+     VMEM.
 
 Four chips (``--four-chips``, and nothing else): TokenRing ``sp_attention``
 (zigzag layout, causal, pipelined overlap executor) on a
@@ -52,6 +59,10 @@ SERVE_ARGS = [
     "--max-pages", "160",
 ]
 GREEDY_STEPS = 8  # decode steps over which pallas and xla tokens must agree
+# The qwen3-longdoc cell's configuration: its ``serving`` block sizes the
+# engine whose steps phase 4 compiles.
+CELL_CONFIG = ROOT / "bench" / "configs" / "qwen3-1.7b-serve.json"
+CELL_STEP_KEYS = ("max_batch", "max_len", "page_size", "prefill_chunk", "max_pages")
 
 
 def _fail(msg: str, code: int = 1):
@@ -158,19 +169,20 @@ def check_kernels(checks, *, seed, impl="pallas", B=2, S=2048, page=128):
             bt[b, ip] = pg
             n = min(page, int(L) - ip * page)
             pos_pool[pg, :n] = np.arange(ip * page, ip * page + n)
-    pools = [
-        jnp.asarray(rng.standard_normal((n_pages, page, HKV, D)), bf16)
+    pools = [  # a one-layer stacked pool
+        jnp.asarray(rng.standard_normal((1, n_pages, page, HKV, D)), bf16)
         for _ in range(2)
     ]
     qd = jnp.asarray(rng.standard_normal((nb, 1, HQ, D)), bf16)
     q_pos = (lengths - 1).astype(np.int32)[:, None]
     out, lse = jax.jit(partial(paged_decode_attention, impl=impl))(
-        qd, *pools, jnp.asarray(pos_pool), jnp.asarray(bt), jnp.asarray(q_pos)
+        qd, *pools, jnp.asarray(pos_pool), jnp.asarray(bt), jnp.asarray(q_pos),
+        jnp.int32(0),
     )
     mapped = bt < n_pages
     safe = np.where(mapped, bt, 0)
     k_view, v_view = (
-        np.where(mapped[:, :, None, None, None], np.asarray(p, np.float32)[safe], 0.0)
+        np.where(mapped[:, :, None, None, None], np.asarray(p[0], np.float32)[safe], 0.0)
         .reshape(nb, W * page, HKV, D)
         for p in pools
     )
@@ -234,6 +246,63 @@ def check_serving(checks, *, seed, impl="pallas", args=None):
     same = sum(pallas[u][:GREEDY_STEPS] == xla.get(u, [])[:GREEDY_STEPS] for u in pallas)
     print(f"info: {same}/{len(pallas)} requests agree over the first "
           f"{GREEDY_STEPS} decode steps")
+
+
+def check_step_memory(checks, *, impl="pallas", cfg=None, max_batch, max_len,
+                      page_size, prefill_chunk, max_pages):
+    """Compile the engine's two donated paged steps from shapes alone (no
+    weights, no pool allocated) and print each one's ``memory_analysis()``;
+    the whole K/V pool must be aliased to the step's result, no layer of it
+    copied, and the prefill step must read the layer it gathers its views
+    from into VMEM (``hlo_analysis.pool_layer_ops``), once for K and once
+    for V: the same gather addressed into HBM made that step 40% slower."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import ARCHS
+    from repro.core.api import ParallelContext
+    from repro.launch.hlo_analysis import pool_layer_ops
+    from repro.models import build_model
+    from repro.serving.engine import paged_steps
+    from repro.serving.kv_cache import pages_for
+
+    if cfg is None:
+        cfg = ARCHS["qwen3-1.7b"].with_(dtype="bfloat16", param_dtype="bfloat16")
+    bundle = build_model(cfg, ParallelContext(mesh=None, impl=impl))
+    slot_pages = pages_for(max_len, page_size)
+    params = jax.eval_shape(bundle.init, jax.random.PRNGKey(0))
+    state = jax.eval_shape(
+        lambda: bundle.init_paged_state(max_pages, page_size, max_batch, slot_pages))
+    pool_bytes = 2 * state["k"].size * state["k"].dtype.itemsize
+    decode, prefill = paged_steps(bundle)
+    i32 = jnp.int32
+    steps = {
+        "decode": (decode, jax.ShapeDtypeStruct((max_batch,), i32),
+                   jax.ShapeDtypeStruct((max_batch,), jnp.bool_)),
+        "prefill": (prefill, jax.ShapeDtypeStruct((max_batch, prefill_chunk), i32),
+                    jax.ShapeDtypeStruct((3, max_batch), i32)),
+    }
+    for name, (fn, tokens, rows) in steps.items():
+        compiled = fn.lower(params, tokens, state, rows).compile()
+        mem = compiled.memory_analysis()
+        print(f"step memory {name}: alias {mem.alias_size_in_bytes} temp "
+              f"{mem.temp_size_in_bytes} argument {mem.argument_size_in_bytes} "
+              f"output {mem.output_size_in_bytes} bytes; K/V pool {pool_bytes}")
+        checks.expect(
+            f"{name} step aliases the K/V pool", mem.alias_size_in_bytes >= pool_bytes,
+            f"alias {mem.alias_size_in_bytes} >= pool {pool_bytes}",
+        )
+        if jax.default_backend() != "tpu":  # the VMEM read is the TPU compiler's
+            continue
+        ops = pool_layer_ops(compiled.as_text(), state["k"].shape)
+        checks.expect(f"{name} step copies no layer of the pool", not ops["moves"],
+                      f"{ops['moves']} == []")
+        reads = 2 if name == "prefill" else 0
+        checks.expect(
+            f"{name} step reads {reads} layers into VMEM",
+            len(ops["vmem_layer_reads"]) == reads,
+            f"{len(ops['vmem_layer_reads'])} == {reads}",
+        )
 
 
 def check_tokenring(checks, *, seed, impl="pallas", S=32768, P=4):
@@ -357,6 +426,9 @@ def main(argv=None) -> int:
         checks.phase("kernels vs float32 oracle", check_kernels, seed=args.seed)
         checks.phase("serve qwen3-1.7b: pallas, then xla", check_serving,
                      seed=args.seed)
+        serving = json.loads(CELL_CONFIG.read_text())["serving"]
+        checks.phase("paged steps at the qwen3-longdoc cell's shapes",
+                     check_step_memory, **{k: serving[k] for k in CELL_STEP_KEYS})
     if checks.failed:
         _fail(f"FAILED: {', '.join(checks.failed)}")
     print(json.dumps({"ok": True, "device": {

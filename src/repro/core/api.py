@@ -705,9 +705,9 @@ class ParallelContext:
         impl = self.impl
         block_k = self.decode_block_k
 
-        def local_fn(q, k_pool, v_pool, pos_pool, bt, qp, lengths):
+        def local_fn(q, k_pool, v_pool, layer, pos_pool, bt, qp, lengths):
             return sp_paged_decode_attention(
-                q, k_pool, v_pool, pos_pool, bt, qp, axis_names=axes,
+                q, k_pool, v_pool, pos_pool, bt, qp, layer, axis_names=axes,
                 lengths=lengths, window=window, scale=scale, impl=impl,
                 block_k=block_k,
             )
@@ -715,13 +715,14 @@ class ParallelContext:
         return ExecutionPlan(
             kind="decode", strategy="decode", inner=None, mesh=self.mesh,
             in_specs=(
-                qspec,                     # q (B, 1, Hq, D)
-                P(seq, None, None, None),  # k pool (n_pages, ps, Hkv, D)
-                P(seq, None, None, None),  # v pool
-                P(seq, None),              # pos pool (n_pages, ps)
-                P(dp, None),               # block tables (B, W)
-                P(dp, None),               # q_pos (B, 1)
-                P(dp),                     # lengths (B,)
+                qspec,                           # q (B, 1, Hq, D)
+                P(None, seq, None, None, None),  # k pool (L, n_pages, ps, Hkv, D)
+                P(None, seq, None, None, None),  # v pool
+                P(),                             # layer
+                P(seq, None),                    # pos pool (n_pages, ps)
+                P(dp, None),                     # block tables (B, W)
+                P(dp, None),                     # q_pos (B, 1)
+                P(dp),                           # lengths (B,)
             ),
             out_specs=qspec, local_fn=local_fn, sp_axes=self.sp_axes,
             sp_degree=self.sp_degree,
@@ -1092,6 +1093,7 @@ def sp_decode_paged(
     q,
     k_pool,
     v_pool,
+    layer,
     pos_pool,
     block_tables,
     q_pos,
@@ -1104,9 +1106,10 @@ def sp_decode_paged(
 ):
     """Fused paged decode on global arrays: no materialized KV gather.
 
-    ``q (B, 1, Hq, D)`` replicated over the SP axes; per-layer pools
-    ``k_pool``/``v_pool (n_pages, page_size, Hkv, D)`` and ``pos_pool
-    (n_pages, page_size)`` page-sharded; ``block_tables (B, W)`` int32
+    ``q (B, 1, Hq, D)`` replicated over the SP axes; the stacked pools
+    ``k_pool``/``v_pool (L, n_pages, page_size, Hkv, D)``, read at ``layer``
+    (int32 scalar), and ``pos_pool (n_pages, page_size)`` page-sharded;
+    ``block_tables (B, W)`` int32
     (``n_pages`` sentinel for unmapped entries), ``q_pos (B, 1)`` and
     ``lengths (B,)`` used lengths (clamps the xla oracle's gathered view —
     the fused kernel masks by the pos pool's PAD sentinel instead).
@@ -1117,20 +1120,20 @@ def sp_decode_paged(
 
     if not pctx.active:
         return sp_paged_decode_attention(
-            q, k_pool, v_pool, pos_pool, block_tables, q_pos, axis_names=(),
+            q, k_pool, v_pool, pos_pool, block_tables, q_pos, layer, axis_names=(),
             lengths=lengths, window=window, scale=scale, impl=pctx.impl,
             block_k=pctx.decode_block_k,
         )
 
     shapes = AttnShapes(
-        B=q.shape[0], Sq=q.shape[1], Hq=q.shape[2], Hkv=k_pool.shape[2],
-        D=q.shape[3], Sk=k_pool.shape[0] * k_pool.shape[1],
+        B=q.shape[0], Sq=q.shape[1], Hq=q.shape[2], Hkv=k_pool.shape[3],
+        D=q.shape[3], Sk=k_pool.shape[1] * k_pool.shape[2],
         dtype_bytes=jnp.dtype(q.dtype).itemsize,
     )
     plan = pctx.plan_decode_paged(
         window=window, scale=scale, shapes=shapes, table_pages=table_pages
     )
-    return plan(q, k_pool, v_pool, pos_pool, block_tables, q_pos, lengths)
+    return plan(q, k_pool, v_pool, layer, pos_pool, block_tables, q_pos, lengths)
 
 
 def sp_prefill(

@@ -131,6 +131,7 @@ def sp_paged_decode_attention(
     pos_pool,
     block_tables,
     q_pos,
+    layer,
     *,
     axis_names,
     lengths=None,
@@ -157,12 +158,12 @@ def sp_paged_decode_attention(
     registered ``"decode"`` cost row prices both paths).
 
     ``q (B, Sq=1, Hq, D)`` and ``q_pos (B, 1)`` replicated over the SP axes;
-    per-layer pools ``k_pool``/``v_pool (n_local, page_size, Hkv, D)`` and
-    ``pos_pool (n_local, page_size)`` page-sharded; ``block_tables (B, W)``
-    global page ids.  Returns the merged ``(B, Sq, Hq, D)`` (plus merged lse
-    when ``return_lse``).
+    stacked pools ``k_pool``/``v_pool (L, n_local, page_size, Hkv, D)``, read
+    at ``layer``, and ``pos_pool (n_local, page_size)`` page-sharded;
+    ``block_tables (B, W)`` global page ids.  Returns the merged ``(B, Sq,
+    Hq, D)`` (plus merged lse when ``return_lse``).
     """
-    n_local = k_pool.shape[0]
+    n_local = k_pool.shape[1]
     bt = block_tables.astype(jnp.int32)
     if axis_names:
         idx = jnp.int32(0)
@@ -173,7 +174,7 @@ def sp_paged_decode_attention(
             jnp.logical_and(bt >= lo, bt < lo + n_local), bt - lo, n_local
         )
     out, lse = paged_decode_attention(
-        q, k_pool, v_pool, pos_pool, bt, q_pos,
+        q, k_pool, v_pool, pos_pool, bt, q_pos, layer,
         lengths=lengths, window=window, scale=scale, block_k=block_k,
         impl=impl,
     )

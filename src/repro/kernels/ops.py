@@ -468,6 +468,7 @@ def paged_decode_attention(
     pos_pool,
     block_tables,
     q_pos,
+    layer,
     *,
     lengths=None,
     window: int | None = None,
@@ -475,7 +476,8 @@ def paged_decode_attention(
     block_k: int | None = None,
     impl: str = "auto",
 ):
-    """Paged decode attention over a page-pool KV cache -> ``(out, lse)``.
+    """Paged decode attention over layer ``layer`` of a stacked page-pool KV
+    cache -> ``(out, lse)``.
 
     Dispatches on ``impl`` exactly like :func:`flash_attention`:
 
@@ -488,7 +490,11 @@ def paged_decode_attention(
         is given) and run the jnp flash over it.
       * ``auto`` — pallas on TPU, xla elsewhere.
 
-    Shapes: ``q (B, 1, Hq, D)``, pools ``(n_pages, page_size, Hkv, D)``,
+    The kernel reads the layer where it lies (its index maps take
+    ``layer``); the oracle gathers its view from that layer.
+
+    Shapes: ``q (B, 1, Hq, D)``, pools ``(L, n_pages, page_size, Hkv, D)``,
+    ``layer`` an int32 scalar,
     ``pos_pool (n_pages, page_size) int32``, ``block_tables (B, W) int32``
     (entries ``>= n_pages`` are the unmapped sentinel), ``q_pos (B, 1)``,
     ``lengths (B,)`` used lengths (xla view clamp only — the kernel masks by
@@ -501,7 +507,7 @@ def paged_decode_attention(
         from repro.kernels.paged_attention import paged_decode_fwd_pallas
 
         return paged_decode_fwd_pallas(
-            q, k_pool, v_pool, pos_pool, block_tables, q_pos,
+            q, k_pool, v_pool, pos_pool, block_tables, q_pos, layer,
             window=window, scale=scale,
             interpret=resolved == "pallas_interpret",
         )
@@ -511,10 +517,10 @@ def paged_decode_attention(
     # siblings, keep the layering one-directional at import time.
     from repro.serving.kv_cache import gather_pages, gather_positions, view_indices
 
-    page_size = k_pool.shape[1]
+    page_size = k_pool.shape[2]
     flat_view = view_indices(block_tables, page_size, lengths=lengths)
-    k_view = gather_pages(k_pool, flat_view)
-    v_view = gather_pages(v_pool, flat_view)
+    k_view = gather_pages(k_pool, flat_view, layer)
+    v_view = gather_pages(v_pool, flat_view, layer)
     pos_view = gather_positions(pos_pool, flat_view)
     return flash_attention(
         q, k_view, v_view, q_pos=q_pos, k_pos=pos_view,

@@ -10,6 +10,11 @@ maps* read it to address the page pool directly, so the Mosaic pipeline
 streams exactly the pages a request maps — no dense view ever exists.
 
 Design (mirrors the PR-3 kernel family in ``flash_attention.py``):
+  * The pools are the serve state's stacked ``(L, n_pages, page_size, Hkv,
+    D)`` K/V, read where they lie: the layer index is a scalar-prefetch
+    operand beside the block table and the index maps return ``(layer, page,
+    0, 0, 0)``, so a model step that carries the pool through its layer scan
+    never slices a layer out of it (a one-layer caller passes ``L = 1``).
   * Grid is ``(B, W)`` with ``W`` the block-table width (logical pages per
     slot); the page dimension is sequential (``arbitrary``) so the
     online-softmax state for one request lives in VMEM scratch across
@@ -112,6 +117,7 @@ def _paged_decode_kernel(
     # scalar-prefetch refs (SMEM) — also fed to the BlockSpec index maps
     bt_ref,  # (B, W)  int32  block tables; sentinel == n_pages means unmapped
     qp_ref,  # (B, 1)  int32  query position (== used length) per request
+    layer_ref,  # (1,) int32  the pools' layer (read by the index maps only)
     # pipelined VMEM refs
     q_ref,  # (Hkv, group, D) q.dtype — every KV head's query group
     k_ref,  # (page_size, Hkv, D)     — one whole physical pool page
@@ -197,6 +203,7 @@ def paged_decode_fwd_pallas(
     pos_pool,
     block_tables,
     q_pos,
+    layer,
     *,
     window: int | None = None,
     scale: float | None = None,
@@ -205,7 +212,8 @@ def paged_decode_fwd_pallas(
     """Fused paged decode attention — no materialized KV gather.
 
     Shapes: ``q (B, 1, Hq, D)`` (one decode token per request),
-    ``k_pool/v_pool (n_pages, page_size, Hkv, D)``,
+    ``k_pool/v_pool (L, n_pages, page_size, Hkv, D)`` read at ``layer`` (an
+    int32 scalar, traced or not),
     ``pos_pool (n_pages, page_size) int32`` (``PAD_POS`` in unwritten slots),
     ``block_tables (B, W) int32`` (entry ``>= n_pages`` == unmapped sentinel),
     ``q_pos (B, 1) int32``.  Returns ``(out, lse)`` with ``out (B, 1, Hq, D)``
@@ -214,7 +222,7 @@ def paged_decode_fwd_pallas(
     """
     B, Sq, Hq, D = q.shape
     assert Sq == 1, f"paged decode kernel is single-token (Sq={Sq})"
-    n_pages, page_size, Hkv, Dk = k_pool.shape
+    _, n_pages, page_size, Hkv, Dk = k_pool.shape
     assert Dk == D and v_pool.shape == k_pool.shape
     assert Hq % Hkv == 0, (Hq, Hkv)
     group = Hq // Hkv
@@ -224,6 +232,7 @@ def paged_decode_fwd_pallas(
 
     bt = block_tables.astype(jnp.int32)
     qp = q_pos.astype(jnp.int32)
+    lyr = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
 
     kernel = functools.partial(
         _paged_decode_kernel,
@@ -233,23 +242,23 @@ def paged_decode_fwd_pallas(
         num_pages_grid=W,
     )
 
-    # Index maps address the pool through the scalar-prefetched table.  The
-    # clamp keeps the sentinel's prefetch in-bounds; the kernel body skips it
-    # from the raw entry (see _paged_decode_kernel).
-    def _page_map(b, ip, bt_ref, qp_ref):
-        return (page_index_clamp(bt_ref[b, ip], n_pages), 0, 0, 0)
+    # Index maps address the pool through the scalar-prefetched layer and
+    # table.  The clamp keeps the sentinel's prefetch in-bounds; the kernel
+    # body skips it from the raw entry (see _paged_decode_kernel).
+    def _page_map(b, ip, bt_ref, qp_ref, layer_ref):
+        return (layer_ref[0], page_index_clamp(bt_ref[b, ip], n_pages), 0, 0, 0)
 
-    def _pos_map(b, ip, bt_ref, qp_ref):
+    def _pos_map(b, ip, bt_ref, qp_ref, layer_ref):
         return (page_index_clamp(bt_ref[b, ip], n_pages), 0, 0)
 
     heads = (None, Hkv, group)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block_tables, q_pos
+        num_scalar_prefetch=3,  # block_tables, q_pos, layer
         grid=(B, W),
         in_specs=[
             pl.BlockSpec(heads + (D,), lambda b, ip, *_: (b, 0, 0, 0)),
-            pl.BlockSpec((None, page_size, Hkv, D), _page_map),
-            pl.BlockSpec((None, page_size, Hkv, D), _page_map),
+            pl.BlockSpec((None, None, page_size, Hkv, D), _page_map),
+            pl.BlockSpec((None, None, page_size, Hkv, D), _page_map),
             pl.BlockSpec((None, 1, page_size), _pos_map),
         ],
         out_specs=[
@@ -276,7 +285,7 @@ def paged_decode_fwd_pallas(
         interpret=interpret,
         name="paged_decode",
     )(
-        bt, qp, q.reshape(B, Hkv, group, D), k_pool, v_pool,
+        bt, qp, lyr, q.reshape(B, Hkv, group, D), k_pool, v_pool,
         pos_pool.reshape(n_pages, 1, page_size),
     )
     return out.reshape(B, 1, Hq, D), lse[..., 0].reshape(B, 1, Hq)

@@ -39,7 +39,7 @@ import re
 from collections import defaultdict
 from dataclasses import dataclass, field
 
-__all__ = ["analyze_hlo", "overlap_report", "HloStats"]
+__all__ = ["analyze_hlo", "overlap_report", "pool_layer_ops", "HloStats"]
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -534,3 +534,55 @@ def analyze_hlo(hlo: str, *, world: int, ring_sizes: dict | None = None) -> HloS
                 stats.link_bytes_bwd += per_dir
 
     return stats
+
+
+def pool_layer_ops(hlo: str, pool_shape) -> dict:
+    """The top-level instructions of a compiled paged serving step whose
+    result holds a whole layer of the K/V pool ``(L, n_pages, page, Hkv,
+    D)`` or more: ``(..., Hkv, D)`` with at least ``n_pages * page`` rows, in
+    any layout.  Loop plumbing (parameters, tuples, the while loop,
+    bitcasts), the in-place scatter fusions that write the step's tokens and
+    the gather fusions that build the per-slot views are not counted.
+    Returns ``{"vmem_layer_reads": [...], "moves": [...]}``:
+
+      * ``vmem_layer_reads`` — dynamic-slice fusions of exactly one layer
+        into VMEM (memory space ``S(1)``): how the prefill step reads the
+        layer it gathers its views from;
+      * ``moves`` — everything else, which for a step that updates its
+        donated pool in place should be nothing.  A scan that slices the
+        pool per layer shows here as dynamic-slice and dynamic-update-slice
+        fusions of a layer, an undonated pool as a copy of the whole pool,
+        and a layer read left in HBM as a dynamic-slice fusion without
+        ``S(1)``.
+    """
+    _, n_pages, page, heads, dim = pool_shape
+    rows = n_pages * page
+    comps = _split_computations(hlo)
+    fused = set(re.findall(r"calls=%?([\w.\-]+)", hlo))
+    out = {"vmem_layer_reads": [], "moves": []}
+    for name, lines in comps.items():
+        if name in fused:
+            continue
+        for line in lines:
+            m = re.match(r"^(?:ROOT\s+)?%?([\w.\-]+) = (.*?)\s([a-z][\w\-]*)\((.*)$", line)
+            if not m:
+                continue
+            inst, typ, op, rest = m.groups()
+            shapes = [tuple(int(d) for d in dims.split(",") if d)
+                      for _, dims in _SHAPE_RE.findall(typ)]
+            if not any(s[-2:] == (heads, dim) and math.prod(s[:-2]) >= rows
+                       for s in shapes):
+                continue
+            if op in ("parameter", "get-tuple-element", "tuple", "while", "bitcast"):
+                continue
+            called = re.search(r"calls=%?([\w.\-]+)", rest)
+            body = comps.get(called.group(1), []) if called else []
+            if op == "fusion" and any(re.search(r" (scatter|gather)\(", x) for x in body):
+                continue
+            entry = f"{inst} = {op} {typ.strip()}"
+            if ("dynamic-slice" in inst and "S(1)" in typ and len(shapes) == 1
+                    and math.prod(shapes[0][:-2]) == rows):
+                out["vmem_layer_reads"].append(entry)
+            else:
+                out["moves"].append(entry)
+    return out

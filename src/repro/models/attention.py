@@ -10,9 +10,10 @@ Entry points sharing one parameter set:
   * ``attention_decode_paged`` / ``attention_prefill_chunk_paged`` — the same
                            two serving steps against the paged page pool
                            (``serving/kv_cache.py``): scatter into owned
-                           pages, gather the block-table view, and run the
-                           identical SP attention — a paged read is
-                           numerically the dense read,
+                           pages of the stacked pool at the layer's index,
+                           read the block-table view, and run the identical
+                           SP attention — a paged read is numerically the
+                           dense read,
   * ``cross_attention``  — encoder-decoder cross attention (resident KV =
                            TokenRing's natural fit).
 """
@@ -206,6 +207,7 @@ def attention_decode_paged(
     positions,
     k_pool,
     v_pool,
+    layer,
     pos_pool,
     block_tables,
     lengths,
@@ -218,11 +220,15 @@ def attention_decode_paged(
     rope: bool = True,
     table_pages: int | None = None,
 ):
-    """Paged decode step: ``x (B,1,d)``; pools ``(n_pages,ps,Hkv,D)``.
+    """Paged decode step: ``x (B,1,d)``; the stacked pools
+    ``(L,n_pages,ps,Hkv,D)`` at layer ``layer`` (int32 scalar).
 
-    ``pos_pool (n_pages, ps)`` is the position pool *already updated* for
-    this step (shared across layers); ``block_tables (B, W)`` the slots'
-    page maps; ``lengths (B,)`` the post-write used lengths.
+    The new K/V scatter into the whole pool at ``[layer, write_page,
+    write_off]``, so a layer scan that carries the pool updates it in place;
+    the kernel reads the layer where it lies.  ``pos_pool (n_pages, ps)`` is
+    the position pool *already updated* for this step (shared across
+    layers); ``block_tables (B, W)`` the slots' page maps; ``lengths (B,)``
+    the post-write used lengths.
     ``write_page``/``write_off (B,)`` locate the new token's physical slot
     (``n_pages`` sentinel drops skipped rows).  The new K/V scatter into the
     pool first, then attention dispatches on the resolved kernel impl:
@@ -242,21 +248,23 @@ def attention_decode_paged(
 
     B, S, _ = x.shape
     q, k, v = _project_qkv(p, x, positions, cfg, rope=rope, pctx=pctx)
-    kp = k_pool.at[write_page, write_off].set(k[:, 0].astype(k_pool.dtype), mode="drop")
-    vp = v_pool.at[write_page, write_off].set(v[:, 0].astype(v_pool.dtype), mode="drop")
+    kp = k_pool.at[layer, write_page, write_off].set(
+        k[:, 0].astype(k_pool.dtype), mode="drop")
+    vp = v_pool.at[layer, write_page, write_off].set(
+        v[:, 0].astype(v_pool.dtype), mode="drop")
     if FlashConfig(impl=pctx.impl).resolve_impl() == "xla":
         page_size = pos_pool.shape[1]
         flat_view = view_indices(block_tables, page_size, lengths=lengths)
         pos_view = gather_positions(pos_pool, flat_view)
-        k_view = constrain(gather_pages(kp, flat_view), pctx, _view_spec(pctx))
-        v_view = constrain(gather_pages(vp, flat_view), pctx, _view_spec(pctx))
+        k_view = constrain(gather_pages(kp, flat_view, layer), pctx, _view_spec(pctx))
+        v_view = constrain(gather_pages(vp, flat_view, layer), pctx, _view_spec(pctx))
         out = sp_decode(
             q, k_view, v_view, pos_view, positions, pctx=pctx, window=window,
             table_pages=table_pages,
         )
     else:
         out = sp_decode_paged(
-            q, kp, vp, pos_pool, block_tables, positions, lengths,
+            q, kp, vp, layer, pos_pool, block_tables, positions, lengths,
             pctx=pctx, window=window, table_pages=table_pages,
         )
     y = dense(p["wo"], out.reshape(B, S, -1), jnp.dtype(cfg.dtype))
@@ -269,6 +277,7 @@ def attention_prefill_chunk_paged(
     positions,
     k_pool,
     v_pool,
+    layer,
     pos_view,
     flat_view,
     write_page,
@@ -282,22 +291,24 @@ def attention_prefill_chunk_paged(
 ):
     """Paged chunked-prefill step: ``x (B,C,d)`` against the gathered view.
 
-    Every row's chunk K/V scatter into the pool first (``write_page``/
-    ``write_off (B,C)`` carry the drop sentinel for invalid tokens); then
-    each row gathers its resident view from the *written* pool, so a row
-    sees what earlier rows of the same step wrote.  ``pos_view`` holds only
-    the positions before each row's own start (``PAD_POS`` elsewhere): the
-    resident partial never sees the row's own tokens, which are attended
-    locally inside ``sp_prefill``.  Returns ``(y, k_pool', v_pool')``.
+    Pools are the stacked ``(L,n_pages,ps,Hkv,D)`` at layer ``layer``, as in
+    :func:`attention_decode_paged`.  Every row's chunk K/V scatter into the
+    pool first (``write_page``/``write_off (B,C)`` carry the drop sentinel
+    for invalid tokens); then each row gathers its resident view from the
+    *written* pool, so a row sees what earlier rows of the same step wrote.
+    ``pos_view`` holds only the positions before each row's own start
+    (``PAD_POS`` elsewhere): the resident partial never sees the row's own
+    tokens, which are attended locally inside ``sp_prefill``.  Returns
+    ``(y, k_pool', v_pool')``.
     """
     from repro.serving.kv_cache import gather_pages
 
     B, C, _ = x.shape
     q, k, v = _project_qkv(p, x, positions, cfg, rope=rope, pctx=pctx)
-    kp = k_pool.at[write_page, write_off].set(k.astype(k_pool.dtype), mode="drop")
-    vp = v_pool.at[write_page, write_off].set(v.astype(v_pool.dtype), mode="drop")
-    k_view = constrain(gather_pages(kp, flat_view), pctx, _view_spec(pctx))
-    v_view = constrain(gather_pages(vp, flat_view), pctx, _view_spec(pctx))
+    kp = k_pool.at[layer, write_page, write_off].set(k.astype(k_pool.dtype), mode="drop")
+    vp = v_pool.at[layer, write_page, write_off].set(v.astype(v_pool.dtype), mode="drop")
+    k_view = constrain(gather_pages(kp, flat_view, layer), pctx, _view_spec(pctx))
+    v_view = constrain(gather_pages(vp, flat_view, layer), pctx, _view_spec(pctx))
     out = sp_prefill(
         q, k, v, positions, k_view, v_view, pos_view, positions,
         pctx=pctx, window=window, table_pages=table_pages,

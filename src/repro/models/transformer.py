@@ -93,6 +93,15 @@ def _moe_add(acc, counts):
     return None if acc is None else {k: acc[k] + counts[k] for k in acc}
 
 
+def _layers_xs(params, cfg):
+    """What the paged steps scan over: each layer's parameters and its index.
+    The stacked KV pool rides in the scan's carry instead, and each layer
+    writes its tokens into it at its index, so the pool is never rebuilt
+    from per-layer outputs: a step whose state is donated updates it in
+    place."""
+    return params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)
+
+
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
@@ -414,21 +423,21 @@ def lm_prefill_chunk_paged(
     x = params["embed"]["table"][token_ids].astype(jnp.dtype(cfg.dtype))
 
     def body(carry, xs):
-        x, n = carry
-        p_l, kc_l, vc_l = xs
+        x, n, kp, vp = carry
+        p_l, layer = xs
         h = apply_norm(p_l["ln1"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
-        y, kc_l, vc_l = attention_prefill_chunk_paged(
-            p_l["attn"], h, positions, kc_l, vc_l, pos_view, flat_view,
+        y, kp, vp = attention_prefill_chunk_paged(
+            p_l["attn"], h, positions, kp, vp, layer, pos_view, flat_view,
             write_page, write_off, cfg=cfg, pctx=pctx, window=cfg.window,
             table_pages=bt.shape[1],
         )
         x = x + y
         h = apply_norm(p_l["ln2"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
         y, _, counts = _ffn(p_l, h, cfg, pctx, valid)
-        return (x + y, _moe_add(n, counts)), (kc_l, vc_l)
+        return (x + y, _moe_add(n, counts), kp, vp), None
 
-    (x, n), (ks, vs) = jax.lax.scan(
-        body, (x, _moe_zero(cache)), (params["layers"], cache["k"], cache["v"])
+    (x, n, ks, vs), _ = jax.lax.scan(
+        body, (x, _moe_zero(cache), cache["k"], cache["v"]), _layers_xs(params, cfg)
     )
     x = apply_norm(params["final_norm"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
     last_idx = jnp.clip(n_valid - 1, 0, C - 1)
@@ -483,21 +492,21 @@ def lm_decode_step_paged(params, token_ids, cache, active=None, *, cfg, pctx):
     x = params["embed"]["table"][token_ids[:, None]].astype(jnp.dtype(cfg.dtype))
 
     def body(carry, xs):
-        x, n = carry
-        p_l, kc_l, vc_l = xs
+        x, n, kp, vp = carry
+        p_l, layer = xs
         h = apply_norm(p_l["ln1"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
-        y, kc_l, vc_l = attention_decode_paged(
-            p_l["attn"], h, positions, kc_l, vc_l, pos_pool, bt, new_len,
+        y, kp, vp = attention_decode_paged(
+            p_l["attn"], h, positions, kp, vp, layer, pos_pool, bt, new_len,
             write_page, write_off, cfg=cfg, pctx=pctx, window=cfg.window,
             table_pages=bt.shape[1],
         )
         x = x + y
         h = apply_norm(p_l["ln2"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
         y, _, counts = _ffn(p_l, h, cfg, pctx, valid[:, None])
-        return (x + y, _moe_add(n, counts)), (kc_l, vc_l)
+        return (x + y, _moe_add(n, counts), kp, vp), None
 
-    (x, n), (ks, vs) = jax.lax.scan(
-        body, (x, _moe_zero(cache)), (params["layers"], cache["k"], cache["v"])
+    (x, n, ks, vs), _ = jax.lax.scan(
+        body, (x, _moe_zero(cache), cache["k"], cache["v"]), _layers_xs(params, cfg)
     )
     x = apply_norm(params["final_norm"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
     logits = jnp.einsum(

@@ -78,7 +78,27 @@ from repro.serving.resilience import (
     import_serving_state,
 )
 
-__all__ = ["Request", "ServingEngine"]
+__all__ = ["Request", "ServingEngine", "paged_steps"]
+
+
+def paged_steps(bundle, *, donate: bool = True):
+    """The engine's two jitted paged steps: ``(decode, prefill)``.
+
+    ``decode(params, tokens (B,), state, active (B,))`` and
+    ``prefill(params, tokens (R, C), state, rows (3, R))``, where ``rows``
+    holds each row's valid tokens, slot and start, uploaded as one array.
+    Each returns ``(logits, state')``.  With ``donate`` the state (argument
+    2) is donated: the pool carried through the layer scan is then updated
+    in place, and only the returned state may be used afterwards.  Without
+    it, every step writes a fresh copy of the whole pool.
+    """
+    def prefill_chunk_paged(params, tokens, state, rows):
+        # Named for the compiled module (``jit_prefill_chunk_paged``).
+        return bundle.prefill_chunk_paged(params, tokens, state, *rows)
+
+    donated = (2,) if donate else ()
+    return (jax.jit(bundle.decode_step_paged, donate_argnums=donated),
+            jax.jit(prefill_chunk_paged, donate_argnums=donated))
 
 
 @dataclass
@@ -135,6 +155,11 @@ class ServingEngine:
       * ``preempt`` — paged mode: when a decode step cannot allocate a page,
         evict the newest request (free its pages, re-queue it, re-prefill
         from its retained tokens) instead of raising.
+
+    Off the CPU, paged mode's two steps take the serve state donated
+    (:func:`paged_steps`) and update the KV pool in place: ``self.state`` is
+    rebound to every step's result, and a state passed to a step is gone
+    after it.
     """
 
     def __init__(self, bundle, params, *, max_batch: int, max_len: int,
@@ -208,14 +233,11 @@ class ServingEngine:
             self.state = bundle.init_paged_state(
                 self.max_pages, page_size, max_batch, self.slot_pages
             )
-            self._step = jax.jit(bundle.decode_step_paged)
-
-            def prefill_chunk_paged(params, tokens, state, rows):
-                # ``rows (3, R)``: each row's valid tokens, slot and start,
-                # uploaded as one array.  Named for the compiled module.
-                return bundle.prefill_chunk_paged(params, tokens, state, *rows)
-
-            self._chunk_step = jax.jit(prefill_chunk_paged)
+            # Not on the CPU, the test backend: there a caller that wraps a
+            # step (the benchmark's planted faults) may still read the state
+            # it passed in.
+            self._step, self._chunk_step = paged_steps(
+                bundle, donate=jax.default_backend() != "cpu")
             self._chunked = True
         else:
             self.page_size = None

@@ -27,7 +27,8 @@ Three layers live here:
     stripes across the ring — the gather re-establishes the sequence-sharded
     view ``sp_decode`` / ``sp_prefill`` already consume.
   * pure-JAX index helpers (:func:`view_indices`, :func:`write_coords`,
-    :func:`gather_pages`) shared by the paged model steps
+    :func:`gather_pages`) shared by the paged model steps, which carry the
+    stacked pool through their layer scan and address a layer by index
     (``models/transformer.py``) — one place owns the page-table arithmetic.
 
 Sentinel convention: an unmapped block-table entry holds ``n_pages`` (one
@@ -41,6 +42,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -583,14 +585,21 @@ def write_coords(block_tables, logical_slots, valid, n_pages: int, page_size: in
     return page, logical_slots % page_size
 
 
-def gather_pages(pool, flat_view):
-    """Gather ``pool (n_pages, page_size, ...)`` into per-slot views.
+def gather_pages(pool, flat_view, layer):
+    """Gather layer ``layer`` of ``pool (L, n_pages, page_size, ...)`` into
+    per-slot views.
 
-    ``flat_view (B, V)`` from :func:`view_indices` -> ``(B, V, ...)``.
-    Out-of-pool indices (unmapped pages) fill with zeros — harmless because
-    their positions fill with ``PAD_POS`` and the kernel masks on position.
+    ``flat_view (B, V)`` from :func:`view_indices` -> ``(B, V, ...)``.  The
+    layer is read whole first and the view gathered from that read: the TPU
+    compiler stages it in VMEM, where the gather's row reads are cheap; the
+    same gather addressed into the stacked pool in HBM is far slower.  The
+    pool itself is only read, so a caller that carries it updates it in
+    place.  Out-of-pool indices (unmapped pages) fill with zeros — harmless
+    because their positions fill with ``PAD_POS`` and the kernel masks on
+    position.
     """
-    flat_pool = pool.reshape((-1,) + pool.shape[2:])
+    layer_pool = jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
+    flat_pool = layer_pool.reshape((-1,) + pool.shape[3:])
     return jnp.take(flat_pool, flat_view, axis=0, mode="fill", fill_value=0)
 
 

@@ -75,3 +75,25 @@ def test_serving_check_reduced(smoke):
     checks = smoke.Checks()
     smoke.check_serving(checks, seed=0, impl="pallas_interpret", args=args)
     assert checks.failed == []
+
+
+def test_step_memory_check_reduced(smoke):
+    """The step-memory phase on the reduced qwen3-1.7b: both donated paged
+    steps compile on the CPU and alias the whole K/V pool."""
+    from repro.configs import ARCHS
+
+    checks = smoke.Checks()
+    smoke.check_step_memory(
+        checks, impl="xla", cfg=ARCHS["qwen3-1.7b"].reduced(), max_batch=4,
+        max_len=128, page_size=16, prefill_chunk=16, max_pages=24,
+    )
+    assert checks.failed == []
+
+
+def test_step_memory_phase_sized_from_the_cell_config(smoke):
+    """Phase 4 takes the engine's sizes from the qwen3-longdoc cell's
+    configuration, so it compiles the steps the benchmark runs."""
+    import json
+
+    serving = json.loads(smoke.CELL_CONFIG.read_text())["serving"]
+    assert all(isinstance(serving[k], int) for k in smoke.CELL_STEP_KEYS)

@@ -364,7 +364,7 @@ def test_paged_decode_matches_oracle(impl, case):
         case_id, ps, heads, lengths
     )
     out, lse = paged_decode_attention(
-        q, k_pool, v_pool, pos_pool, bt, q_pos,
+        q, k_pool[None], v_pool[None], pos_pool, bt, q_pos, 0,
         lengths=jnp.asarray(lengths, jnp.int32), window=window, impl=impl,
     )
     k_view, v_view, pos_view = _materialize_view(k_pool, v_pool, pos_pool, bt)
@@ -401,12 +401,42 @@ def test_paged_decode_dead_row_is_merge_identity(impl):
     v_pool = v_pool.at[n_pages - 1].set(1e3)
     pos_pool = pos_pool.at[n_pages - 1].set(0)
     out, lse = paged_decode_attention(
-        q, k_pool, v_pool, pos_pool, bt, q_pos,
+        q, k_pool[None], v_pool[None], pos_pool, bt, q_pos, 0,
         lengths=jnp.asarray([9, 0], jnp.int32), impl=impl,
     )
     np.testing.assert_array_equal(np.asarray(out[1]), 0.0)
     assert np.all(np.isneginf(np.asarray(lse[1])))
     assert np.all(np.isfinite(np.asarray(out[0]))), "live row unaffected"
+
+
+@pytest.mark.parametrize("impl", ["pallas_interpret", "xla"])
+@pytest.mark.parametrize("case_id", ["ps4_gqa", "ps8_window"])
+def test_paged_decode_reads_layer_of_stacked_pool(impl, case_id):
+    """The kernel reads layer ``l`` of a stacked ``(L, n_pages, ...)`` pool
+    where it lies (scalar-prefetched layer index; the oracle gathers its
+    view from that layer) and gives bit for bit what the one-layer call on
+    the slice ``pool[l]`` gives: every layer, unmapped sentinel tails, a
+    window, and the sentinel's clamped alias page live in every layer."""
+    from repro.kernels.ops import paged_decode_attention
+
+    _, ps, heads, lengths, window = next(c for c in PAGED_CASES if c[0] == case_id)
+    q, k0, v0, pos_pool, bt, q_pos = _paged_case_data(case_id, ps, heads, lengths)
+    n_layers = 3
+    scale = jnp.arange(1, n_layers + 1, dtype=jnp.float32)[:, None, None, None, None]
+    k_pool = jnp.flip(k0, 0)[None] * scale  # each layer holds other K/V
+    v_pool = v0[None] - scale
+    lens = jnp.asarray(lengths, jnp.int32)
+    for layer in range(n_layers):
+        got = paged_decode_attention(
+            q, k_pool, v_pool, pos_pool, bt, q_pos, jnp.int32(layer),
+            lengths=lens, window=window, impl=impl,
+        )
+        want = paged_decode_attention(
+            q, k_pool[layer][None], v_pool[layer][None], pos_pool, bt, q_pos, 0,
+            lengths=lens, window=window, impl=impl,
+        )
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w), err_msg=f"layer {layer}")
 
 
 def test_paged_decode_vmem_shapes_lintable():

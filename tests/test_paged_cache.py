@@ -30,7 +30,7 @@ from repro.serving.kv_cache import (
     pages_for,
 )
 
-from test_serving import GREEDY_TOL, _legacy_step, assert_greedy_chain_matches
+from test_serving import GREEDY_TOL, _legacy_step, assert_greedy_chain_matches, donated
 
 PCTX = ParallelContext(mesh=None, impl="xla")
 
@@ -218,7 +218,7 @@ def test_view_indices_lengths_clamp_masks_stale_pages():
 
     flat = view_indices(bt, ps, lengths=lengths)
     pos = np.asarray(gather_positions(pos_pool, flat))[0]
-    kv = np.asarray(gather_pages(k_pool, flat))[0]
+    kv = np.asarray(gather_pages(k_pool[None], flat, 0))[0]
     # Used pages, in table order (reversed page ids), fully visible...
     np.testing.assert_array_equal(pos[:ps], [0, 1, 2, 3])
     np.testing.assert_array_equal(pos[ps:ps + 2], [4, 5])
@@ -300,20 +300,27 @@ def test_engine_paged_long_prompt_beyond_dense_slab():
         )
 
 
-def test_engine_paged_preemption_requeue_round_trip():
+@pytest.mark.parametrize("donate", [False, True], ids=["copied", "donated"])
+def test_engine_paged_preemption_requeue_round_trip(donate):
     """Forced preemption: the newest request is evicted when decode growth
     drains the pool, re-queues, re-prefills from prompt + generated tokens,
-    and finishes with an oracle-exact chain; pages fully return to the pool."""
+    and finishes with an oracle-exact chain; pages fully return to the pool.
+    With the state donated the steps update the pool in place: a state
+    passed to a step is gone after it, and the chains are the same."""
     cfg, bundle, params = _setup()
     rng = np.random.default_rng(0)
     # 8-page x 4-token pool; each request grows to ceil(20/4) = 5 pages
-    eng = ServingEngine(
+    eng = donated(ServingEngine(
         bundle, params, max_batch=2, max_len=64, prefill_chunk=4,
         page_size=4, max_pages=8,
-    )
+    ), donate)
     r1 = eng.submit(rng.integers(1, 90, 9), max_new_tokens=12)
     r2 = eng.submit(rng.integers(1, 90, 9), max_new_tokens=12)
     done = eng.run()
+    before = eng.state
+    _, eng.state = eng._step(params, jnp.zeros((2,), jnp.int32), before,
+                             jnp.zeros((2,), bool))  # no row decodes
+    assert before["k"].is_deleted() == donate
     s = eng.stats()
     assert len(done) == 2
     assert s["preemptions"] >= 1, "pool was sized to force a preemption"
@@ -472,16 +479,17 @@ def test_engine_prefix_warm_hit_skips_prefill_and_matches_cold():
     assert_greedy_chain_matches(bundle, params, warm, 2, 64, step)
 
 
-def test_engine_prefix_cow_divergence_never_mutates_shared_page():
+@pytest.mark.parametrize("donate", [False, True], ids=["copied", "donated"])
+def test_engine_prefix_cow_divergence_never_mutates_shared_page(donate):
     """A prompt diverging *inside* a resident page decodes oracle-exact via
     a private copy (exactly one COW), and the resident page's K/V bytes are
-    untouched."""
+    untouched, whether or not the steps take the state donated."""
     cfg, bundle, params = _setup()
     rng = np.random.default_rng(7)
     base = list(rng.integers(1, 90, 25))
     fork = base[:20] + [(t + 1) % 90 + 1 for t in base[20:]]  # page-3 split
 
-    eng = _prefix_engine(bundle, params)
+    eng = donated(_prefix_engine(bundle, params), donate)
     eng.submit(base, max_new_tokens=6)
     eng.run()
     shared = sorted(eng.prefix.pages)
@@ -582,7 +590,8 @@ def _prefill_gather_before_write(params, token_ids, cache, n_valid, *, cfg):
         h = T.apply_norm(p_l["ln1"], x, norm_type=cfg.norm_type, eps=cfg.norm_eps)
         q, k, v = _project_qkv(p_l["attn"], h, positions, cfg, pctx=PCTX)
         out = sp_prefill(
-            q, k, v, positions, gather_pages(kc, flat), gather_pages(vc, flat),
+            q, k, v, positions, gather_pages(kc[None], flat, 0),
+            gather_pages(vc[None], flat, 0),
             old_pos, positions, pctx=PCTX, table_pages=bt.shape[1],
         )
         x = x + dense(p_l["attn"]["wo"], out.reshape(B, C, -1), jnp.dtype(cfg.dtype))
@@ -764,14 +773,15 @@ def test_engine_overlapping_prompts_each_get_a_row_while_one_decodes():
         assert_greedy_chain_matches(bundle, params, r, 2, 64, step)
 
 
-def test_engine_preemption_re_prefill_exact_under_packing():
+@pytest.mark.parametrize("donate", [False, True], ids=["copied", "donated"])
+def test_engine_preemption_re_prefill_exact_under_packing(donate):
     """A preempted request re-prefills its prompt and output in packed rows
     and resumes exactly; the prefill tokens are what every admission had
-    left to prefill, no more and no fewer."""
+    left to prefill, no more and no fewer; the same with the state donated."""
     cfg, bundle, params = _setup()
     rng = np.random.default_rng(0)
-    eng = ServingEngine(bundle, params, max_batch=4, max_len=64, prefill_chunk=4,
-                        page_size=4, max_pages=12)
+    eng = donated(ServingEngine(bundle, params, max_batch=4, max_len=64, prefill_chunk=4,
+                                page_size=4, max_pages=12), donate)
     left = _admitted_prompt_tokens(eng)
     reqs = [eng.submit(rng.integers(1, 90, 17), max_new_tokens=14) for _ in range(2)]
     eng.run()

@@ -31,6 +31,7 @@ from repro.serving.resilience import (
     IntegrityError,
     LoadShedError,
 )
+from test_serving import donated
 
 PCTX = ParallelContext(mesh=None, impl="xla")
 
@@ -301,13 +302,13 @@ def test_ladder_gates_prefix_splicing_then_sharing():
 # ---------------------------------------------------------------------------
 
 
-def _mid_flight_engine(tmp_path=None, **overrides):
+def _mid_flight_engine(tmp_path=None, donate=False, **overrides):
     cfg, bundle, params = _ctx()
     kw = dict(ENGINE_KW)
     if tmp_path is not None:
         kw["snapshot_dir"] = str(tmp_path)
     kw.update(overrides)
-    eng = ServingEngine(bundle, params, **kw)
+    eng = donated(ServingEngine(bundle, params, **kw), donate)
     for p in WORKLOADS["standard"]:
         eng.submit(p, max_new_tokens=_N_NEW["standard"])
     eng.run(max_steps=3)  # prompts part-prefilled: genuinely mid-flight
@@ -373,11 +374,13 @@ def test_integrity_error_without_snapshot_is_fatal():
         eng.run()
 
 
-def test_integrity_error_restores_snapshot_and_completes(tmp_path):
+@pytest.mark.parametrize("donate", [False, True], ids=["copied", "donated"])
+def test_integrity_error_restores_snapshot_and_completes(tmp_path, donate):
     """Corruption found by the periodic audit feeds the recovery path: the
-    engine restores its latest snapshot and finishes bit-identical."""
+    engine restores its latest snapshot (``restore_snapshot`` in the same
+    engine) and finishes bit-identical, with donated steps too."""
     want = _oracle("standard")
-    eng = _mid_flight_engine(tmp_path, audit_every=1)
+    eng = _mid_flight_engine(tmp_path, audit_every=1, donate=donate)
     eng.snapshot()
     page = next(int(p) for p in eng._bt.ravel() if p != eng.NULL)
     eng.alloc._free.append(page)
@@ -396,15 +399,19 @@ def test_integrity_error_restores_snapshot_and_completes(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_snapshot_kill_restart_token_exact(tmp_path):
+@pytest.mark.parametrize("donate", [False, True], ids=["copied", "donated"])
+def test_snapshot_kill_restart_token_exact(tmp_path, donate):
+    """Snapshot mid-flight, kill, restart: token-exact, also when both
+    engines' steps take the serve state donated (the snapshot reads the
+    state between steps, the restart restores into a fresh one)."""
     cfg, bundle, params = _ctx()
     want = _oracle("standard")
-    eng = _mid_flight_engine(tmp_path)
+    eng = _mid_flight_engine(tmp_path, donate=donate)
     step = eng.snapshot()
     assert eng._ckpt.latest_step() == step
     del eng  # the kill: every live object is gone
 
-    eng2 = ServingEngine.from_snapshot(bundle, params, str(tmp_path))
+    eng2 = donated(ServingEngine.from_snapshot(bundle, params, str(tmp_path)), donate)
     eng2.auditor.check()  # restored state passes the full invariant sweep
     done = eng2.run()
     assert {r.uid: list(r.output) for r in done} == want

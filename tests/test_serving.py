@@ -22,7 +22,7 @@ import pytest
 from repro.configs import ARCHS
 from repro.core.api import ParallelContext
 from repro.models import build_model
-from repro.serving.engine import Request, ServingEngine
+from repro.serving.engine import Request, ServingEngine, paged_steps
 
 PCTX = ParallelContext(mesh=None, impl="xla")
 
@@ -83,20 +83,38 @@ def _legacy_step(bundle):
     return jax.jit(lambda p, t, s: bundle.decode_step(p, t, s))
 
 
-def test_engine_matches_manual_greedy():
+def donated(eng, donate=True):
+    """``eng`` with its paged steps jitted as on a chip, the serve state
+    donated (the engine keeps it on the CPU): the pool is updated in place.
+    ``donate=False`` leaves the engine as it is."""
+    if donate:
+        eng._step, eng._chunk_step = paged_steps(eng.bundle)
+    return eng
+
+
+def _engine(bundle, params, paged, **kw):
+    """The dense slab, or the paged pool updated in place by donated steps."""
+    if not paged:
+        return ServingEngine(bundle, params, **kw)
+    return donated(ServingEngine(bundle, params, page_size=8, prefill_chunk=8, **kw))
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged_donated"])
+def test_engine_matches_manual_greedy(paged):
     cfg, bundle, params = _setup()
     prompt = [5, 17, 3, 42]
     n_new = 6
-    eng = ServingEngine(bundle, params, max_batch=2, max_len=64)
+    eng = _engine(bundle, params, paged, max_batch=2, max_len=64)
     req = eng.submit(prompt, max_new_tokens=n_new)
     eng.run()
     assert len(req.output) == n_new
     assert_greedy_chain_matches(bundle, params, req, 2, 64, _legacy_step(bundle))
 
 
-def test_engine_continuous_batching_multiple_requests():
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged_donated"])
+def test_engine_continuous_batching_multiple_requests(paged):
     cfg, bundle, params = _setup()
-    eng = ServingEngine(bundle, params, max_batch=2, max_len=64)
+    eng = _engine(bundle, params, paged, max_batch=2, max_len=64)
     reqs = [eng.submit([3 + i, 9, 27], max_new_tokens=4) for i in range(5)]
     done = eng.run()
     assert len(done) == 5

@@ -111,15 +111,15 @@ def test_paged_decode_compiles_for_v5e(one_chip, page):
     bf16, i32 = jnp.bfloat16, jnp.int32
     args = (
         _spec((BATCH, 1, HQ, D), bf16, one_chip),
-        _spec((n_pages, page, HKV, D), bf16, one_chip),
-        _spec((n_pages, page, HKV, D), bf16, one_chip),
+        _spec((1, n_pages, page, HKV, D), bf16, one_chip),  # a one-layer pool
+        _spec((1, n_pages, page, HKV, D), bf16, one_chip),
         _spec((n_pages, page), i32, one_chip),
         _spec((BATCH, W), i32, one_chip),
         _spec((BATCH, 1), i32, one_chip),
     )
 
     def decode(q, kp, vp, pp, bt, qp):
-        return paged_decode_attention(q, kp, vp, pp, bt, qp, impl="pallas")
+        return paged_decode_attention(q, kp, vp, pp, bt, qp, 0, impl="pallas")
 
     _compiled_text(decode, *args)
 
@@ -174,14 +174,14 @@ def _named_kernels_text(kernel, sharding):
         bf16, i32 = jnp.bfloat16, jnp.int32
         args = (
             _spec((BATCH, 1, HQ, D), bf16, sharding),
-            _spec((n_pages, PAGE, HKV, D), bf16, sharding),
-            _spec((n_pages, PAGE, HKV, D), bf16, sharding),
+            _spec((1, n_pages, PAGE, HKV, D), bf16, sharding),
+            _spec((1, n_pages, PAGE, HKV, D), bf16, sharding),
             _spec((n_pages, PAGE), i32, sharding),
             _spec((BATCH, W), i32, sharding),
             _spec((BATCH, 1), i32, sharding),
         )
         return _compiled_text(
-            lambda *a: paged_decode_attention(*a, impl="pallas"), *args)
+            lambda *a: paged_decode_attention(*a, 0, impl="pallas"), *args)
 
     def loss(q, k, v, pos):
         out, lse = flash_attention(q, k, v, q_pos=pos, k_pos=pos, causal=True, impl="pallas")
@@ -204,34 +204,104 @@ def test_kernels_named_in_compiled_v5e_hlo(one_chip, kernel):
         f"no custom call named {kernel} among {names}")
 
 
-@pytest.mark.parametrize("step", ["prefill", "decode", "prefill_8_rows"])
+def _paged_step_compiled(cfg, sharding, step, *, B, R, pages, slot_pages, C=256):
+    """A paged serving step compiled for the described v5e exactly as the
+    engine jits it (``serving.engine.paged_steps``: the state donated).
+    Returns the compiled program and the abstract serve state."""
+    from repro.core.api import ParallelContext
+    from repro.models import build_model
+    from repro.serving.engine import paged_steps
+
+    bundle = build_model(cfg, ParallelContext(mesh=None, impl="pallas"))
+    place = lambda t: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: _spec(a.shape, a.dtype, sharding), t)
+    params = place(jax.eval_shape(bundle.init, jax.random.PRNGKey(0)))
+    state = place(jax.eval_shape(lambda: bundle.init_paged_state(pages, PAGE, B, slot_pages)))
+    decode, prefill = paged_steps(bundle)
+    i32 = jnp.int32
+    if step == "prefill":
+        fn, args = prefill, (params, _spec((R, C), i32, sharding), state,
+                             _spec((3, R), i32, sharding))
+    else:
+        fn, args = decode, (params, _spec((B,), i32, sharding), state,
+                            _spec((B,), jnp.bool_, sharding))
+    compiled = fn.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), "no Pallas kernel in the compiled program"
+    return compiled, state, jax.eval_shape(fn, *args)
+
+
+def _assert_pool_updated_in_place(compiled, state, step, *, temp_below=None):
+    """The donated pool is aliased to the step's result and no whole layer of
+    it is copied (``hlo_analysis.pool_layer_ops``); the prefill step reads
+    the layer it gathers its views from into VMEM, one read for K and one
+    for V, since the same gather addressed into HBM made that step 40%
+    slower on the chip.  Optionally the step's temporary buffers stay under
+    ``temp_below`` bytes."""
+    from repro.launch.hlo_analysis import pool_layer_ops
+
+    pool = state["k"]
+    pool_bytes = 2 * pool.size * pool.dtype.itemsize  # K and V
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes, (mem.alias_size_in_bytes, pool_bytes)
+    if temp_below is not None:
+        assert mem.temp_size_in_bytes < temp_below, (mem.temp_size_in_bytes, temp_below)
+    ops = pool_layer_ops(compiled.as_text(), pool.shape)
+    assert not ops["moves"], ops["moves"]
+    assert len(ops["vmem_layer_reads"]) == (2 if step == "prefill" else 0), ops
+
+
+@pytest.mark.parametrize(
+    "step,pages,B,slot_pages",
+    [("decode", 64, 4, 8), ("prefill", 64, 4, 8),
+     ("decode", 352, 16, 64), ("prefill", 352, 16, 64)],
+    ids=["decode", "prefill", "decode_cell", "prefill_cell"])
+def test_paged_steps_update_pool_in_place_for_v5e(one_chip, step, pages, B, slot_pages):
+    """Two layers of Qwen3-1.7B's paged serving steps at published widths,
+    pages of 128, jitted as the engine jits them: the pool rides in the
+    layer scan's carry, the paged-decode kernel reads a layer by index, the
+    prefill gather reads one layer into VMEM, and the state is donated, so
+    the compiled step aliases the whole K/V pool and copies no layer of it.
+    At 64 pages the decode and prefill temporaries stay under one layer's K;
+    at the ``qwen3-longdoc`` cell's shapes (352 pages, batch 16, 64 pages a
+    slot) the decode step's do, and the prefill step's views of 16 slots of
+    8192 tokens outgrow a layer.  The VMEM read holds up to a layer of 448
+    pages at these widths (117 MB); a pool past that fails here."""
+    from repro.configs.qwen3_1_7b import CONFIG
+
+    cfg = CONFIG.with_(n_layers=2, dtype="bfloat16", param_dtype="bfloat16")
+    compiled, state, _ = _paged_step_compiled(
+        cfg, one_chip, step, B=B, R=B // 2 if pages == 64 else B, pages=pages,
+        slot_pages=slot_pages)
+    k = state["k"]
+    layer_k = k.size * k.dtype.itemsize // k.shape[0]
+    _assert_pool_updated_in_place(
+        compiled, state, step,
+        temp_below=layer_k if step == "decode" or pages == 64 else None)
+
+
+@pytest.mark.parametrize(
+    "step", ["prefill", "decode", "prefill_8_rows",
+             "decode_2_layers", "prefill_8_rows_2_layers"])
 def test_moe_paged_steps_compile_for_v5e(one_chip, step):
     """One layer of Qwen3-30B-A3B's paged serving steps at published widths
     (32 q / 4 kv heads of 128, 128 experts of 768, top-8) at the serving
-    cell's shapes (batch 32, chunk 256, pages of 128, 36 a slot; prefill
-    on 32 rows, and on the cell's 8): the grouped matmuls, the sort and the
-    flash or paged-decode kernel compile, and the step's expert counters
-    come out of the program."""
+    cell's shapes (batch 32, chunk 256, 512 pages of 128, 36 a slot; prefill
+    on 32 rows, and on the cell's 8), jitted as the engine jits them: the
+    grouped matmuls, the sort and the flash or paged-decode kernel compile,
+    and the step's expert counters come out of the program.  At two layers
+    (a layer scan that stays a loop) the donated pool is updated in place,
+    as in ``test_paged_steps_update_pool_in_place_for_v5e``."""
     from repro.configs.qwen3_moe_30b_a3b import CONFIG
-    from repro.core.api import ParallelContext
-    from repro.models import build_model
 
-    cfg = CONFIG.with_(n_layers=1, dtype="bfloat16", param_dtype="bfloat16")
-    bundle = build_model(cfg, ParallelContext(mesh=None, impl="pallas"))
-    B, C, pages, slot_pages = 32, 256, 64, 36
-    place = lambda t: jax.tree_util.tree_map(  # noqa: E731
-        lambda a: _spec(a.shape, a.dtype, one_chip), t)
-    params = place(jax.eval_shape(bundle.init, jax.random.PRNGKey(0)))
-    state = place(jax.eval_shape(lambda: bundle.init_paged_state(pages, PAGE, B, slot_pages)))
+    two = step.endswith("_2_layers")
+    cfg = CONFIG.with_(n_layers=2 if two else 1, dtype="bfloat16", param_dtype="bfloat16")
+    compiled, state, out = _paged_step_compiled(
+        cfg, one_chip, step.split("_")[0], B=32,
+        R=8 if step.startswith("prefill_8_rows") else 32, pages=512, slot_pages=36)
     assert set(state["moe"]) == {"routed", "touched", "steps"}
-    i32 = jnp.int32
-    if step.startswith("prefill"):
-        R = 8 if step == "prefill_8_rows" else B
-        fn = lambda p, t, s, r: bundle.prefill_chunk_paged(p, t, s, *r)  # noqa: E731
-        args = (params, _spec((R, C), i32, one_chip), state, _spec((3, R), i32, one_chip))
-    else:
-        fn = bundle.decode_step_paged
-        args = (params, _spec((B,), i32, one_chip), state, _spec((B,), jnp.bool_, one_chip))
-    out = jax.eval_shape(fn, *args)
     assert set(out[1]["moe"]) == {"routed", "touched", "steps"}
-    _compiled_text(fn, *args)
+    if two:
+        # No temp bound here: the scan still copies each layer's expert
+        # stacks out of the scanned weights, a temporary far above one
+        # layer's K.
+        _assert_pool_updated_in_place(compiled, state, step.split("_")[0])
